@@ -83,6 +83,8 @@ def main(argv=None) -> int:
             return 2
         selected = [b for b in BENCHES if any(k in b for k in keys)]
 
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     os.makedirs(args.out, exist_ok=True)
     print("bench,name,value,detail")
     failures = []

@@ -254,17 +254,15 @@ def build_probe_parallel_step(
         return new_params, {"cost": cost.astype(jnp.float32),
                             "c_tilde_mean": jnp.mean(jnp.abs(all_c))}
 
-    from repro.distributed.compat import shard_map
-
     def _wrap(pspec_tree):
         manual = {probe_axis} | _spec_axes(pspec_tree) | _spec_axes(batch_specs)
         if data_axis is not None:
             manual.add(data_axis)
-        shard = shard_map(
+        shard = jax.shard_map(
             run, mesh=mesh,
             in_specs=(pspec_tree, P(), batch_specs),
             out_specs=(pspec_tree, P()),
-            manual_axes=manual,
+            axis_names=frozenset(manual), check_vma=False,
         )
 
         @jax.jit

@@ -75,11 +75,11 @@ def pipeline_forward(stage_fn, stage_params, x, *, mesh, axis="pod",
         _, outs = jax.lax.fori_loop(0, total, tick, (buf, out0))
         return outs[None]  # [1, m, mb, ...] — stacked over stages outside
 
-    from .compat import shard_map
-    shard = shard_map(
+    shard = jax.shard_map(
         run, mesh=mesh,
         in_specs=(P(axis), P()),   # params sharded by stage; x replicated
         out_specs=P(axis),         # per-stage outputs; last stage is real
+        check_vma=False,
     )
     xq = x.reshape(m, mb, *x.shape[1:])
     outs = shard(stage_params, xq)          # [n_stages, m, mb, ...]

@@ -99,24 +99,17 @@ def mgd_update(
 # path while still paying only read-W + write-W in HBM traffic.
 
 
-def _window_kernel(lseeds_ref, coefs_ref, w_ref, o_ref, *,
-                   alpha, dtheta, bk, bn, n_cols, window):
+def _window_kernel(lseeds_ref, terms_ref, w_ref, o_ref, *,
+                   bk, bn, n_cols, window):
     i = pl.program_id(0)
     j = pl.program_id(1)
     idx_g = _tile_index(i * bk, j * bn, bk, bn, n_cols)
 
     def body(t, w32):
-        sgn = _index_signs(idx_g, lseeds_ref[t])
-        # association mirrors tree_scale→tree_axpy: α·((Δθ·sgn)·coef) =
-        # sgn·(α·(Δθ·coef)) exactly (sgn = ±1 commutes through both
-        # roundings), computed sign-LAST so the multiply feeding the add
-        # is exact — FMA contraction of mul+add then cannot move the
-        # result off the reference optimizer's two-rounding chain, and
-        # needs no barrier to survive fusion.  The scalar-chain barriers
-        # keep XLA from merging the α and Δθ constants into one factor.
-        term = jax.lax.optimization_barrier(
-            alpha * jax.lax.optimization_barrier(dtheta * coefs_ref[t]))
-        return w32 + sgn * term
+        # sign-LAST: sgn = ±1 makes the multiply feeding the add exact,
+        # so FMA contraction of mul+add cannot move the result off the
+        # reference optimizer's two-rounding chain
+        return w32 + _index_signs(idx_g, lseeds_ref[t]) * terms_ref[t]
 
     w32 = jax.lax.fori_loop(
         0, window, body, w_ref[...].astype(jnp.float32)
@@ -151,10 +144,17 @@ def mgd_update_window(
     assert kdim % bk == 0 and n % bn == 0, (w.shape, bk, bn)
     window = lseeds.shape[0]
     assert coefs.shape == (window,)
+    # association mirrors tree_scale→tree_axpy: α·((Δθ·sgn)·coef) =
+    # sgn·(α·(Δθ·coef)) exactly (sgn = ±1 commutes through both
+    # roundings).  The J scalars are computed here, outside the kernel
+    # (Mosaic cannot lower optimization_barrier); the barriers keep XLA
+    # from merging the α and Δθ constants into one factor.
+    pin = jax.lax.optimization_barrier
+    terms = pin(jnp.float32(alpha) * pin(
+        jnp.float32(dtheta) * jnp.asarray(coefs, jnp.float32)))
 
     kernel = functools.partial(
-        _window_kernel, alpha=float(alpha), dtheta=float(dtheta),
-        bk=bk, bn=bn, n_cols=n_cols or n, window=window,
+        _window_kernel, bk=bk, bn=bn, n_cols=n_cols or n, window=window,
     )
     return pl.pallas_call(
         kernel,
@@ -166,4 +166,4 @@ def mgd_update_window(
         ),
         out_shape=jax.ShapeDtypeStruct((kdim, n), w.dtype),
         interpret=interpret,
-    )(jnp.asarray(lseeds, jnp.uint32), jnp.asarray(coefs, jnp.float32), w)
+    )(jnp.asarray(lseeds, jnp.uint32), terms, w)

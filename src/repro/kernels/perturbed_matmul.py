@@ -61,7 +61,10 @@ def _index_signs(idx, lseed):
     — the ONE in-kernel copy of the host hash (perturbations.rademacher_
     signs); every kernel that regenerates θ̃ must go through here."""
     h = _fmix32(idx * _GOLDEN + lseed)
-    return 1.0 - 2.0 * (h >> np.uint32(31)).astype(jnp.float32)
+    # the top bit is the int32 sign bit; Mosaic has no uint32 → f32 cast,
+    # and exact ±1 is the same value the host's 1 − 2·bit gives
+    neg = jax.lax.bitcast_convert_type(h, jnp.int32) < 0
+    return jnp.where(neg, jnp.float32(-1.0), jnp.float32(1.0))
 
 
 def _tile_signs(lseed, k0, n0, bk, bn, n_cols):

@@ -15,8 +15,14 @@ the probe axis (core/probe_parallel.py) or a pipeline axis
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.distributed.compat import make_mesh
+
+def make_mesh(axis_shapes, axis_names):
+    """``jax.make_mesh`` with every axis Auto (jax's default is Explicit):
+    the probe and pipeline steps shard by in/out specs, not by type."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_shapes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

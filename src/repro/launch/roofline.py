@@ -25,6 +25,9 @@ import math
 import os
 from typing import Dict, List
 
+# Unkeyed peaks of one chip kind, for the dry-run model only.  Nothing that
+# measures on a chip may read them: a measured roofline share takes its
+# peaks from a table keyed by the device's ``device_kind``.
 PEAK_FLOPS = 197e12         # bf16 / chip
 HBM_BW = 819e9              # bytes/s / chip
 LINK_BW = 50e9              # bytes/s / link

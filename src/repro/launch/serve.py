@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config, get_smoke_config
+from repro.launch.cache import use_compile_cache
 from repro.models import model_forward, model_init, model_loss
 from repro.serving import greedy_generate
 
@@ -91,7 +92,7 @@ def _serve_online(args, cfg, params):
         while (svc.stats()["trim_global_step"] < args.trim_steps
                and time.time() < deadline):
             time.sleep(0.02)
-        svc.fence()
+        svc.fence()             # re-raises a failed trimmer's error
         svc.publish()
         stats = svc.stats()
         c1 = corpus_cost(svc.snapshot().params)
@@ -105,6 +106,9 @@ def _serve_online(args, cfg, params):
         print(f"[serve]   served cost {c0:.4f} -> {c1:.4f} "
               f"({'improved' if c1 < c0 else 'no improvement'}"
               f"{', drifting plant' if args.drift > 0 else ''})")
+        if stats["trim_global_step"] < args.trim_steps:
+            raise SystemExit(f"[serve] only {stats['trim_global_step']} of "
+                             f"{args.trim_steps} trim steps ran")
 
 
 def main():
@@ -129,6 +133,7 @@ def main():
     ap.add_argument("--dtheta", type=float, default=1e-3)
     ap.add_argument("--probes", type=int, default=4)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.family in ("vlm", "audio"):

@@ -3,7 +3,7 @@
 
 Trains an assigned architecture with MGD (or the backprop baseline) on the
 synthetic LM stream.  ``--smoke`` selects the reduced config (CPU-runnable);
-the full configs are exercised via the dry-run (launch/dryrun.py).
+without it the published config is built as is.
 Checkpoints are atomic and resumable (--ckpt-dir); a killed run restarted
 with the same flags reproduces the exact trajectory.
 """
@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from repro.configs import get_config, get_smoke_config
 from repro.core import MGDConfig
 from repro.data.pipeline import lm_sampler
+from repro.launch.cache import use_compile_cache
 from repro.models import model_init, model_loss
 from repro.training.train_loop import (TrainLoopConfig, train_backprop,
                                        train_mgd)
@@ -43,6 +44,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--chunk", type=int, default=20)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = model_init(cfg, jax.random.PRNGKey(args.seed))

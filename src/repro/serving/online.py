@@ -482,6 +482,7 @@ class OnlineService:
         self._queue: queue.Queue = queue.Queue(maxsize=self.cfg.queue_depth)
         self._stop = threading.Event()
         self._threads: list = []
+        self._trim_error: Optional[Exception] = None
         self._started = False
         self._closed = False
         self._lock = threading.Lock()
@@ -512,7 +513,8 @@ class OnlineService:
 
     def close(self) -> None:
         """Stop threads, flush the queue (pending requests get a
-        RuntimeError, never a hang), fence the plant.  Idempotent."""
+        RuntimeError, never a hang), fence the plant.  Idempotent.
+        Re-raises the error that stopped the background trimmer."""
         if self._closed:
             return
         self._closed = True
@@ -530,6 +532,7 @@ class OnlineService:
             self._queue.task_done()
         if self.trimmer is not None:
             self.trimmer.fence()
+        self._raise_trim_error()
 
     def __enter__(self) -> "OnlineService":
         return self.start()
@@ -544,7 +547,8 @@ class OnlineService:
     def fence(self, timeout: Optional[float] = None) -> None:
         """Drain in-flight serving work (queued + mid-decode requests),
         then fence the trimmer's plant — after this, every submitted
-        request has been answered and every parameter write has landed."""
+        request has been answered and every parameter write has landed.
+        Re-raises the error that stopped the background trimmer."""
         deadline = time.monotonic() + (timeout if timeout is not None
                                        else DEFAULT_TIMEOUT_S)
         with self._queue.all_tasks_done:
@@ -557,6 +561,7 @@ class OnlineService:
                         f"{self._queue.unfinished_tasks} requests in flight")
         if self.trimmer is not None:
             self.trimmer.fence()
+        self._raise_trim_error()
 
     # -- serving ------------------------------------------------------------
 
@@ -703,10 +708,19 @@ class OnlineService:
                 del self._latencies[:-4096]
 
     def _trim_loop(self) -> None:
-        while not self._stop.is_set():
-            took = self.trimmer.step(4)
-            if not took:
-                self._stop.wait(0.005)
+        # the thread boundary: an error here would otherwise end the
+        # thread silently; fence() and close() re-raise it
+        try:
+            while not self._stop.is_set():
+                took = self.trimmer.step(4)
+                if not took:
+                    self._stop.wait(0.005)
+        except Exception as e:          # noqa: BLE001 — surfaced by fence
+            self._trim_error = e
+
+    def _raise_trim_error(self) -> None:
+        if self._trim_error is not None:
+            raise self._trim_error
 
 
 # ---------------------------------------------------------------------------

@@ -223,8 +223,12 @@ def train_mgd(
         loop.checkpoint_every, loop.log, loop.recal_every,
         loop.recal_params)
     # shadow captured from the caller's arguments BEFORE any resume
-    # restore — the factory calibration, identical across restarts
-    shadow = recal_params if recal_params is not None else params
+    # restore — the factory calibration, identical across restarts.  Held
+    # only when recalibration will read it: otherwise it would pin a
+    # second copy of the initial weights in device memory for the run.
+    shadow = None
+    if recal_every:
+        shadow = recal_params if recal_params is not None else params
     drv = resolve_driver(loss_fn, cfg, probe_fn=loop.probe_fn,
                          plant=loop.plant, mesh=loop.mesh,
                          algorithm=loop.algorithm)

@@ -74,7 +74,7 @@ def test_param_specs_right_alignment():
 def test_probe_parallel_converges():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp
-        from repro.distributed.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 2), ("pod", "data"))
         from repro.core.mgd import MGDConfig
         from repro.core.probe_parallel import build_probe_parallel_step
@@ -100,7 +100,7 @@ def test_probe_parallel_converges():
 def test_pipeline_forward_exact():
     out = _run_subprocess("""
         import jax, jax.numpy as jnp
-        from repro.distributed.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((4,), ("pod",))
         from repro.distributed.pipeline import pipeline_forward
         key = jax.random.PRNGKey(0)
@@ -124,7 +124,7 @@ def test_sharded_mgd_step_runs_on_mesh():
     8-device (2,4) mesh with the production sharding rules."""
     out = _run_subprocess("""
         import jax, jax.numpy as jnp, functools
-        from repro.distributed.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         mesh = make_mesh((2, 4), ("data", "model"))
         from repro.configs import get_smoke_config
         from repro.core import MGDConfig, build_mgd_step, mgd_init
@@ -160,7 +160,7 @@ def test_elastic_restore_across_meshes(tmp_path):
     out = _run_subprocess(f"""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from repro.distributed.compat import make_mesh
+        from repro.launch.mesh import make_mesh
         from repro.training import checkpoint as ckpt
         params = {{"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}}
         mesh1 = make_mesh((2, 4), ("data", "model"))

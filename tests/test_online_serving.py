@@ -16,6 +16,7 @@ Load-bearing contracts:
   to the flat-kwarg path, which fires ONE PendingDeprecationWarning.
 """
 import threading
+import time
 import warnings
 
 import jax
@@ -266,6 +267,28 @@ def test_fence_drains_inflight_requests():
         assert all(f.done() for f in futs)
     finally:
         svc.close()
+
+
+def test_trimmer_error_surfaces_from_fence_and_close():
+    """A background trim step that raises ends the trim thread; fence()
+    and close() re-raise its error instead of the service carrying on as
+    if trimming were healthy."""
+    def broken_loss(p, b):
+        raise ValueError("broken cost oracle")
+
+    cfg = ServiceConfig(slots=4, min_fill=4, trim_batch=4, publish_every=5,
+                        batch_window_s=0.001)
+    trim = TrimConfig(DriverConfig(dtheta=5e-2, eta=0.2), broken_loss)
+    svc = repro.serve(cfg, _predict, _params(), trim=trim)
+    _traffic(svc, n=8)          # fills the replay buffer past min_fill
+    with pytest.raises(ValueError, match="broken cost oracle"):
+        deadline = time.monotonic() + 30
+        while time.monotonic() < deadline:
+            svc.fence()
+            time.sleep(0.01)
+    with pytest.raises(ValueError, match="broken cost oracle"):
+        svc.close()
+    assert svc.closed
 
 
 def test_ragged_request_shape_is_loud():
