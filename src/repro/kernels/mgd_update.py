@@ -82,6 +82,7 @@ def mgd_update(
         ),
         out_shape=jax.ShapeDtypeStruct((kdim, n), w.dtype),
         interpret=interpret,
+        name="mgd_update",
     )(jnp.asarray(lseeds, jnp.uint32), jnp.asarray(coefs, jnp.float32), w)
 
 
@@ -166,4 +167,5 @@ def mgd_update_window(
         ),
         out_shape=jax.ShapeDtypeStruct((kdim, n), w.dtype),
         interpret=interpret,
+        name="mgd_update_window",
     )(jnp.asarray(lseeds, jnp.uint32), terms, w)

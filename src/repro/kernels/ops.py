@@ -33,6 +33,12 @@ def default_impl() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "interpret"
 
 
+def _prep():
+    """Device scope of a wrapper's reshapes, pads and output slices around
+    its ``pallas_call`` (metadata only: the compiled ops do not change)."""
+    return jax.named_scope("repro.kernels.prep")
+
+
 def _pad_to(x, mult, axis):
     n = x.shape[axis]
     pad = (-n) % mult
@@ -70,20 +76,22 @@ def perturbed_matmul(x, w, lseed, *, dtheta, sign=1.0, impl=None,
         return _ref.perturbed_matmul_ref(
             x, w, lseed, dtheta=dtheta, sign=sign, out_dtype=out_dtype)
 
-    x2, lead, m = _flatten_lead(x)
     kdim, n = w.shape
-    bm_eff = min(bm, max(8, m))
-    bk_eff = _tile(kdim, bk)
-    bn_eff = _tile(n, bn)
-    x2p = _pad_to(_pad_to(x2, bm_eff, 0), bk_eff, 1)
-    wp = _pad_to(_pad_to(w, bk_eff, 0), bn_eff, 1)
+    with _prep():
+        x2, lead, m = _flatten_lead(x)
+        bm_eff = min(bm, max(8, m))
+        bk_eff = _tile(kdim, bk)
+        bn_eff = _tile(n, bn)
+        x2p = _pad_to(_pad_to(x2, bm_eff, 0), bk_eff, 1)
+        wp = _pad_to(_pad_to(w, bk_eff, 0), bn_eff, 1)
     y = _perturbed_matmul_pallas(
         x2p, wp, lseed, dtheta=dtheta, sign=sign,
         bm=min(bm_eff, x2p.shape[0]), bn=bn_eff, bk=bk_eff,
         out_dtype=out_dtype or x.dtype, n_cols=n,
         interpret=(impl == "interpret"),
     )
-    return y[:m, :n].reshape(*lead, n)
+    with _prep():
+        return y[:m, :n].reshape(*lead, n)
 
 
 def perturbed_matmul_pair(xp, xm, w, lseed, *, dtheta, impl=None,
@@ -98,22 +106,24 @@ def perturbed_matmul_pair(xp, xm, w, lseed, *, dtheta, impl=None,
         return _ref.perturbed_matmul_pair_ref(
             xp, xm, w, lseed, dtheta=dtheta, out_dtype=out_dtype)
 
-    xp2, lead, m = _flatten_lead(xp)
-    xm2, _, _ = _flatten_lead(xm)
     kdim, n = w.shape
-    bm_eff = min(bm, max(8, m))
-    bk_eff = _tile(kdim, bk)
-    bn_eff = _tile(n, bn)
-    xp2 = _pad_to(_pad_to(xp2, bm_eff, 0), bk_eff, 1)
-    xm2 = _pad_to(_pad_to(xm2, bm_eff, 0), bk_eff, 1)
-    wp = _pad_to(_pad_to(w, bk_eff, 0), bn_eff, 1)
+    with _prep():
+        xp2, lead, m = _flatten_lead(xp)
+        xm2, _, _ = _flatten_lead(xm)
+        bm_eff = min(bm, max(8, m))
+        bk_eff = _tile(kdim, bk)
+        bn_eff = _tile(n, bn)
+        xp2 = _pad_to(_pad_to(xp2, bm_eff, 0), bk_eff, 1)
+        xm2 = _pad_to(_pad_to(xm2, bm_eff, 0), bk_eff, 1)
+        wp = _pad_to(_pad_to(w, bk_eff, 0), bn_eff, 1)
     yp, ym = _perturbed_matmul_pair_pallas(
         xp2, xm2, wp, lseed, dtheta=dtheta,
         bm=min(bm_eff, xp2.shape[0]), bn=bn_eff, bk=bk_eff,
         out_dtype=out_dtype or xp.dtype, n_cols=n,
         interpret=(impl == "interpret"),
     )
-    return (yp[:m, :n].reshape(*lead, n), ym[:m, :n].reshape(*lead, n))
+    with _prep():
+        return (yp[:m, :n].reshape(*lead, n), ym[:m, :n].reshape(*lead, n))
 
 
 def _as_matrix(w):
@@ -131,13 +141,15 @@ def mgd_update(w, lseeds, coefs, *, eta, dtheta, impl=None, bk=256, bn=256):
     kdim, n = w.shape
     bk_eff = _tile(kdim, bk)
     bn_eff = _tile(n, bn)
-    wp = _pad_to(_pad_to(w, bk_eff, 0), bn_eff, 1)
+    with _prep():
+        wp = _pad_to(_pad_to(w, bk_eff, 0), bn_eff, 1)
     out = _mgd_update_pallas(
         wp, lseeds, coefs, eta=eta, dtheta=dtheta,
         bk=bk_eff, bn=bn_eff, n_cols=n,
         interpret=(impl == "interpret"),
     )
-    return out[:kdim, :n]
+    with _prep():
+        return out[:kdim, :n]
 
 
 def mgd_update_window(w, lseeds, coefs, *, alpha, dtheta, impl=None,
@@ -158,10 +170,12 @@ def mgd_update_window(w, lseeds, coefs, *, alpha, dtheta, impl=None,
     kdim, n = w2.shape
     bk_eff = _tile(kdim, bk)
     bn_eff = _tile(n, bn)
-    wp = _pad_to(_pad_to(w2, bk_eff, 0), bn_eff, 1)
+    with _prep():
+        wp = _pad_to(_pad_to(w2, bk_eff, 0), bn_eff, 1)
     out = _mgd_update_window_pallas(
         wp, lseeds, coefs, alpha=alpha, dtheta=dtheta,
         bk=bk_eff, bn=bn_eff, n_cols=n,
         interpret=(impl == "interpret"),
     )
-    return out[:kdim, :n].reshape(shape)
+    with _prep():
+        return out[:kdim, :n].reshape(shape)

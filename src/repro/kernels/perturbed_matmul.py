@@ -151,6 +151,7 @@ def perturbed_matmul(
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         interpret=interpret,
+        name="perturbed_matmul",
     )(jnp.asarray(lseed, jnp.uint32).reshape(1), x, w)
 
 
@@ -254,4 +255,5 @@ def perturbed_matmul_pair(
         out_shape=[jax.ShapeDtypeStruct((m, n), out_dtype),
                    jax.ShapeDtypeStruct((m, n), out_dtype)],
         interpret=interpret,
+        name="perturbed_matmul_pair",
     )(jnp.asarray(lseed, jnp.uint32).reshape(1), xp, xm, w)

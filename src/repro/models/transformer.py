@@ -372,6 +372,7 @@ def _pqkv(p, xs, positions, cfg, ids, probe, layer):
     return qs, ks, vs
 
 
+@jax.named_scope("repro.model.attn")
 def _pattn_apply(p, xs, positions, cfg: ArchConfig, ids, probe, layer):
     b, s, _ = xs[0].shape
     qs, ks, vs = _pqkv(p, xs, positions, cfg, ids, probe, layer)
@@ -387,6 +388,7 @@ def _pattn_apply(p, xs, positions, cfg: ArchConfig, ids, probe, layer):
     return pdense(p["wo"], tuple(ys), ids["wo"], probe, layer=layer)
 
 
+@jax.named_scope("repro.model.mlp")
 def _pglu_mlp(p, xs, ids, probe, layer):
     gs = pdense(p["gate"], xs, ids["gate"], probe, layer=layer)
     us = pdense(p["up"], xs, ids["up"], probe, layer=layer)
@@ -395,6 +397,7 @@ def _pglu_mlp(p, xs, ids, probe, layer):
     return pdense(p["down"], hs, ids["down"], probe, layer=layer)
 
 
+@jax.named_scope("repro.model.block")
 def _pblock_apply(p, xs, positions, cfg: ArchConfig, ids, probe, layer):
     seq_ax = "sp" if cfg.seq_parallel else None
     xn = prmsnorm(p["ln1"], xs, ids["ln1"], probe, layer=layer,
@@ -427,17 +430,19 @@ def model_forward_perturbed(params, cfg: ArchConfig, batch, probe):
     assert supports_fused_probe(cfg), cfg.family
     ids = leaf_id_tree(params)
     emb, eids = params["embed"], ids["embed"]
-    tables = pleaf(emb["tok"]["table"], eids["tok"]["table"], probe)
-    if "embeds" in batch:
-        xs = tuple(batch["embeds"] for _ in probe.ctx.signs)
-    elif cfg.n_codebooks:
-        toks = batch["tokens"]
-        _, nq, _ = toks.shape
-        offs = (jnp.arange(nq, dtype=toks.dtype) * cfg.vocab)[None, :, None]
-        xs = tuple(jnp.take(t, toks + offs, axis=0).sum(axis=1)
-                   for t in tables)
-    else:
-        xs = tuple(jnp.take(t, batch["tokens"], axis=0) for t in tables)
+    with jax.named_scope("repro.model.embed"):
+        tables = pleaf(emb["tok"]["table"], eids["tok"]["table"], probe)
+        if "embeds" in batch:
+            xs = tuple(batch["embeds"] for _ in probe.ctx.signs)
+        elif cfg.n_codebooks:
+            toks = batch["tokens"]
+            _, nq, _ = toks.shape
+            offs = (jnp.arange(nq, dtype=toks.dtype)
+                    * cfg.vocab)[None, :, None]
+            xs = tuple(jnp.take(t, toks + offs, axis=0).sum(axis=1)
+                       for t in tables)
+        else:
+            xs = tuple(jnp.take(t, batch["tokens"], axis=0) for t in tables)
     xs = tuple(shard(x, "batch", "sp" if cfg.seq_parallel else None, None)
                for x in xs)
     b, s, _ = xs[0].shape
@@ -449,13 +454,15 @@ def model_forward_perturbed(params, cfg: ArchConfig, batch, probe):
                             l)
         return out, None
 
-    xs, _ = jax.lax.scan(
-        body, xs, (params["layers"], jnp.arange(cfg.n_layers)))
-    xs = prmsnorm(emb["ln_f"], xs, eids["ln_f"], probe, eps=cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = tuple(x @ t.T for x, t in zip(xs, tables))
-    else:
-        logits = pdense(emb["head"], xs, eids["head"], probe)
+    with jax.named_scope("repro.model.layers"):
+        xs, _ = jax.lax.scan(
+            body, xs, (params["layers"], jnp.arange(cfg.n_layers)))
+    with jax.named_scope("repro.model.head"):
+        xs = prmsnorm(emb["ln_f"], xs, eids["ln_f"], probe, eps=cfg.norm_eps)
+        if cfg.tie_embeddings:
+            logits = tuple(x @ t.T for x, t in zip(xs, tables))
+        else:
+            logits = pdense(emb["head"], xs, eids["head"], probe)
     logits = tuple(shard(l, "batch", None, "model") for l in logits)
     if cfg.n_codebooks:
         logits = tuple(l.reshape(b, s, cfg.n_codebooks, cfg.vocab)
@@ -471,8 +478,9 @@ def model_probe_costs(params, cfg: ArchConfig, batch, probe):
     """
     if supports_fused_probe(cfg):
         logits = model_forward_perturbed(params, cfg, batch, probe)
-        return jnp.stack(
-            [_loss_from_logits(l, batch["labels"]) for l in logits])
+        with jax.named_scope("repro.model.loss"):
+            return jnp.stack(
+                [_loss_from_logits(l, batch["labels"]) for l in logits])
     theta = pert.generate(
         params, ptype="rademacher", step=probe.step, seed=probe.seed,
         dtheta=probe.ctx.dtheta, tau_p=probe.ctx.tau_p)

@@ -125,3 +125,41 @@ def test_fused_mgd_step_compiles_and_fits_one_chip(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert used < V5E_HBM_BYTES, used
+
+
+PROGRAM_SCOPES = ("repro.model.embed", "repro.model.layers",
+                  "repro.model.block", "repro.model.attn", "repro.model.mlp",
+                  "repro.model.head", "repro.model.loss", "repro.step.probe",
+                  "repro.step.update", "repro.kernels.prep")
+
+
+def test_fused_step_names_its_kernels_and_program_scopes(one_chip):
+    """The compiled fused step keeps the custom calls' names that a
+    profiler trace shows and the benchmark matches, and carries every
+    program scope in its ops' metadata (a one-layer qwen3-14b: its vocab
+    pads the update kernel's tiles, so prep ops exist)."""
+    import re
+
+    cfg = get_config("qwen3-14b").replace(n_layers=1)
+
+    def on_chip(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    shapes = jax.eval_shape(lambda: model_init(cfg, jax.random.PRNGKey(0)))
+    drv = repro.driver(
+        "discrete",
+        repro.DriverConfig(fused=True, mode="central", kernel_impl="pallas",
+                           dtheta=2 ** -6, eta=2e-4),
+        lambda p, b: model_loss(p, cfg, b),
+        probe_fn=make_transformer_probe_fn(cfg))
+    params = jax.tree_util.tree_map(on_chip, shapes)
+    state = jax.tree_util.tree_map(on_chip, jax.eval_shape(drv.init, shapes))
+    tokens = jax.ShapeDtypeStruct((4, 512), jnp.int32, sharding=one_chip)
+    text = _compile(drv.step, params, state,
+                    {"tokens": tokens, "labels": tokens}).as_text()
+    calls = re.findall(r"^\s*(?:ROOT )?%([\w.\-]+?)(?:\.\d+)? = .*"
+                       r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert set(calls) == {"perturbed_matmul_pair", "mgd_update_window"}
+    scopes = set(re.findall(r"repro\.[a-z]+\.[a-z]+",
+                            " ".join(re.findall(r'op_name="([^"]*)"', text))))
+    assert scopes >= set(PROGRAM_SCOPES)
