@@ -1,6 +1,6 @@
 """The program's own names in a traced window: the device scopes
 (``jax.named_scope("repro.…")``) each op was traced under, and the host
-spans (``TraceAnnotation("repro.…", **counters)``) of its loop.
+spans (``TraceAnnotation("repro.loop.…")``) of its training loop.
 
 A scope is metadata the compiler keeps on each op: the trace's XPlane
 event metadata carries it as the ``tf_op`` stat (JAX's name stack, e.g.
@@ -10,26 +10,29 @@ expose event metadata, so ``op_scopes`` walks the protobuf wire format of
 the ``.xplane.pb`` for it.  An op belongs to the innermost ``repro.*``
 component of its name stack; device time is attributed by self time (an
 op's time less the ops nested in it), so the parts add up to busy time.
+A metric reader is handed only the ``Context``, which does not name its
+trace file: ``of(ctx)`` opens the file where ``run.py`` writes it.
 
-Span counters are the spans' arguments, which ``ProfileData`` returns as
-event stats.  A metric reader is handed only the ``Context``; ``of(ctx)``
-finds the trace it was loaded from under ``out/trace/``.
+The host spans are host events of ``ctx.trace`` like any other; the
+functions below read them from the ``Context`` alone.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import functools
 import re
 from pathlib import Path
 
+from . import bench
 from . import trace as tr
 from .context import KERNELS
 
 OUT = Path(__file__).resolve().parent / "out"
 SCOPE = re.compile(r"repro\.[A-Za-z0-9_.]*[A-Za-z0-9_]")
 PROGRAM = "repro."
-CHUNK = "repro.chunk"
 TF_OP = "tf_op"
+D2H = "D2H Dispatch"    # the runtime's host event for one device→host copy
 
 
 # --- protobuf wire format ---------------------------------------------------
@@ -118,66 +121,15 @@ def innermost(tf_op: str) -> str:
     return found[-1] if found else ""
 
 
-# --- host spans -------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class Span:
-    start: float        # seconds on the trace's clock
-    end: float
-    name: str
-    args: dict          # the span's counters
-
-
-def host_spans(path) -> list:
-    """The program's ``repro.*`` host spans and the harness's window
-    span, by start."""
-    from jax.profiler import ProfileData
-
-    out = []
-    for plane in ProfileData.from_file(str(path)).planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            for e in line.events:
-                if e.name.startswith(PROGRAM) or \
-                        e.name == tr.HARNESS + "window":
-                    out.append(Span(e.start_ns * 1e-9,
-                                    (e.start_ns + e.duration_ns) * 1e-9,
-                                    e.name, dict(e.stats)))
-    return sorted(out, key=lambda s: s.start)
-
-
-def label_gap(host, a: float, b: float) -> str:
-    """What the host was doing in the device gap [a, b]: the program's
-    span (other than ``repro.chunk``, which encloses the whole gap) that
-    overlaps it the most, else ``trace.label_gap``'s label."""
-    best, best_overlap = None, 0.0
-    for e in host:
-        if e.start >= b:
-            break
-        if not e.name.startswith(PROGRAM) or e.name == CHUNK:
-            continue
-        overlap = min(e.end, b) - max(e.start, a)
-        if overlap > best_overlap:
-            best, best_overlap = e.name, overlap
-    return best or tr.label_gap([e for e in host if e.name != CHUNK], a, b)
-
-
-# --- what the metrics read -----------------------------------------------
-
-def _overlap(gaps, a: float, b: float) -> float:
-    return sum(max(0.0, min(g1, b) - max(g0, a)) for g0, g1 in gaps)
-
+# --- device scopes ----------------------------------------------------------
 
 class Program:
-    """The program's scopes and spans in ``ctx``'s window, read from the
+    """The program's device scopes in ``ctx``'s window, read from the
     trace at ``path`` (None: no trace found, every reading None)."""
 
-    def __init__(self, ctx, path=None, spans=None):
+    def __init__(self, ctx, path=None):
         self.ctx = ctx
         self.path = path
-        self.spans = spans if spans is not None else (
-            host_spans(path) if path else [])
 
     @functools.cached_property
     def parts(self) -> dict:
@@ -206,48 +158,77 @@ class Program:
             return None
         return 1e3 * self.parts.get(scope, 0.0) / self.ctx.steps
 
-    def window_spans(self, name: str) -> list:
-        ctx = self.ctx
-        return [s for s in self.spans if s.name == name
-                and s.start >= ctx.t0 and s.end <= ctx.t1]
 
-    def span_idle_ms_per_step(self, name: str):
-        """Device idle ms per step inside the window's ``name`` spans,
-        averaged over the chips; None where the window holds none."""
-        spans, ctx = self.window_spans(name), self.ctx
-        if not spans:
-            return None
-        idle = 0.0
-        for i in range(ctx.chips):
-            gaps = tr.idle_gaps(ctx.trace.devices.get(i, []), ctx.t0, ctx.t1)
-            idle += sum(_overlap(gaps, s.start, s.end) for s in spans)
-        return 1e3 * idle / ctx.chips / ctx.steps
-
-    def span_arg_per_step(self, name: str, arg: str):
-        """The ``arg`` counter of the window's ``name`` spans, summed,
-        per step; None where the window holds no such span."""
-        spans = self.window_spans(name)
-        if not spans:
-            return None
-        return sum(float(s.args.get(arg, 0)) for s in spans) / self.ctx.steps
+def trace_path(ctx):
+    """The run's trace file, where ``run.py`` writes it for the cell whose
+    configuration and traffic ``ctx`` holds (``out/trace/<cell>/``, which
+    it empties before tracing); None where there is none."""
+    for w in bench.load_benchmark()["workloads"]:
+        cell = bench.resolve(w["name"])
+        if cell.config == ctx.config and cell.traffic == ctx.traffic:
+            try:
+                return tr.find_trace(OUT / "trace" / w["name"])
+            except FileNotFoundError:
+                return None
+    return None
 
 
-_last: list = []
+_last: list = []        # [ctx, its Program]: every reader of a run shares one
 
 
 def of(ctx) -> Program:
-    """The ``Program`` of the run's own trace: the newest ``.xplane.pb``
-    under ``out/trace/`` whose window span opens where ``ctx``'s does."""
-    if _last and _last[0] is ctx:
-        return _last[1]
-    found = Program(ctx)
-    files = sorted(OUT.glob("trace/**/*.xplane.pb"),
-                   key=lambda p: p.stat().st_mtime, reverse=True)
-    for path in files:
-        spans = host_spans(path)
-        if any(s.name == tr.HARNESS + "window" and abs(s.start - ctx.t0) < 1e-9
-               for s in spans):
-            found = Program(ctx, path, spans)
-            break
-    _last[:] = [ctx, found]
-    return found
+    """The ``Program`` of the run's own trace."""
+    if not (_last and _last[0] is ctx):
+        _last[:] = [ctx, Program(ctx, trace_path(ctx))]
+    return _last[1]
+
+
+# --- host spans ---------------------------------------------------------
+
+def window_spans(ctx, name: str) -> list:
+    """The host events named ``name`` that lie inside the window."""
+    return [e for e in ctx.trace.host if e.name == name
+            and e.start >= ctx.t0 and e.end <= ctx.t1]
+
+
+def gap_ms_per_step(ctx, span: str):
+    """Device idle ms per step, averaged over the chips, in the idle gaps
+    that ``trace.label_gap`` gives to ``span`` (the host event that
+    overlaps a gap the most): whole gaps, so the reading does not hang on
+    how closely the profiler aligns the device's clock with the host's.
+    None where the window holds no such span."""
+    if not window_spans(ctx, span):
+        return None
+    host = [e for e in ctx.trace.host if e.end > ctx.t0 and e.start < ctx.t1
+            and e.name != tr.HARNESS + "window"]
+    starts = [e.start for e in host]
+    reach = max(e.end - e.start for e in host)
+    idle = 0.0
+    for i in range(ctx.chips):
+        for a, b in tr.idle_gaps(ctx.trace.devices.get(i, []),
+                                 ctx.t0, ctx.t1):
+            near = host[bisect.bisect_left(starts, a - reach):
+                        bisect.bisect_left(starts, b)]
+            if tr.label_gap(near, a, b) == span:
+                idle += b - a
+    return 1e3 * idle / ctx.chips / ctx.steps
+
+
+def span_ms_per_step(ctx, name: str):
+    """Host ms per step inside the window's ``name`` spans; None where
+    the window holds none."""
+    spans = window_spans(ctx, name)
+    if not spans:
+        return None
+    return 1e3 * sum(e.end - e.start for e in spans) / ctx.steps
+
+
+def events_per_step(ctx, span: str, event: str):
+    """Host events named ``event`` that start inside the window's ``span``
+    spans, per step; None where the window holds no such span."""
+    spans = window_spans(ctx, span)
+    if not spans:
+        return None
+    n = sum(1 for e in ctx.trace.host if e.name == event and any(
+        s.start <= e.start < s.end for s in spans))
+    return n / ctx.steps

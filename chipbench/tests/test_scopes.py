@@ -15,14 +15,20 @@ from chipbench.context import KERNELS, Context
 
 DATA = Path(__file__).parent / "data"
 STEPS = 4
-CLOCK = 2e-4        # s: host/device timestamp alignment in the scoped trace
+CLOCK = 2e-4        # s: slack of the clock check
 CELL = "mistral-nemo-12b.online-1x128"
 SCOPE_METRICS = {"model.embed_ms_per_step": "repro.model.embed",
                  "model.layer_slice_ms_per_step": "repro.model.layers",
+                 "model.block_ms_per_step": "repro.model.block",
+                 "model.attn_ms_per_step": "repro.model.attn",
+                 "model.mlp_ms_per_step": "repro.model.mlp",
+                 "model.loss_ms_per_step": "repro.model.loss",
                  "kernels.prep_ms_per_step": "repro.kernels.prep"}
+LOOP_METRICS = ["loop.read_ms_per_step.online",
+                "loop.host_reads_per_step.online",
+                "loop.dispatch_ms_per_step.online"]
 NEW_METRICS = [f"{m}.{k}" for m in SCOPE_METRICS for k in ("train", "online")
-               ] + ["loop.read_ms_per_step.online",
-                    "loop.host_reads_per_step.online"]
+               ] + LOOP_METRICS
 
 
 def _unzip(tmp_path_factory, name):
@@ -94,6 +100,7 @@ def test_no_trace_found_reads_none(plain, monkeypatch, tmp_path):
     _, prog = plain
     monkeypatch.setattr(scopes, "OUT", tmp_path)
     monkeypatch.setattr(scopes, "_last", [])
+    assert scopes.trace_path(prog.ctx) is None
     assert scopes.of(prog.ctx).path is None
     for m in NEW_METRICS:
         assert bench.metric_reader(m)(prog.ctx) is None, m
@@ -128,28 +135,29 @@ def _programs(prog):
 def test_host_spans_and_device_ops_share_a_clock(scoped):
     """The clock check: each program's first op starts after its dispatch
     span opens, and its host read returns after its last op and before
-    the next program's first.  The profiler aligns the TPU's timestamps
-    with the host's to within ``CLOCK``: here the device's run up to
-    0.103 ms early, drifting from one step to the next."""
+    the next program's first, to within ``CLOCK``.  The profiler aligns
+    the TPU's timestamps with the host's only roughly: here each
+    program's first op lies 1.2-1.3 ms before its dispatch span closes,
+    which is when the host has enqueued it, and 0.14-0.24 ms before in
+    other chip runs.  So the loop's metrics take whole gaps or host time
+    alone."""
     _, prog = scoped
-    dispatches = prog.window_spans("repro.loop.dispatch")
-    reads = prog.window_spans("repro.loop.read")
+    ctx = prog.ctx
+    dispatches = scopes.window_spans(ctx, "repro.loop.dispatch")
+    reads = scopes.window_spans(ctx, "repro.loop.read")
     programs = _programs(prog)
     assert len(dispatches) == len(reads) == len(programs) == STEPS
     for i, (d, r) in enumerate(zip(dispatches, reads)):
+        assert d.end <= r.start
         assert programs[i][0].start >= d.start - CLOCK
         assert r.end >= max(e.end for e in programs[i]) - CLOCK
         if i + 1 < len(programs):
+            assert r.end <= dispatches[i + 1].start
             assert r.end <= programs[i + 1][0].start + CLOCK
 
 
-def test_chunk_steps_sum_to_the_window(scoped):
-    _, prog = scoped
-    chunks = prog.window_spans("repro.chunk")
-    assert sum(int(c.args["steps"]) for c in chunks) == STEPS
-
-
 def test_gaps_between_steps_are_the_loops(scoped):
+    """The harness's own gap labels name the loop's spans."""
     _, prog = scoped
     ops = prog.ctx.trace.devices[0]
     gaps = tr.idle_gaps(ops, min(e.start for e in ops),
@@ -157,8 +165,16 @@ def test_gaps_between_steps_are_the_loops(scoped):
     between = sorted(gaps, key=lambda g: g[0] - g[1])[:STEPS - 1]
     assert all(b - a > 1e-3 for a, b in between)
     for a, b in between:
-        assert scopes.label_gap(prog.ctx.trace.host, a, b).startswith(
-            "repro.loop."), (a, b)
+        assert tr.label_gap(prog.ctx.trace.host, a, b) == "repro.loop.read"
+
+
+def test_trace_path_is_where_run_writes_it(scoped, monkeypatch):
+    path, prog = scoped
+    monkeypatch.setattr(scopes, "OUT", path.parent.parent.parent)
+    monkeypatch.setattr(scopes, "_last", [])
+    assert scopes.trace_path(prog.ctx) == path
+    assert scopes.of(prog.ctx).path == path
+    assert scopes.of(prog.ctx) is scopes.of(prog.ctx)
 
 
 def test_metrics_read_the_scoped_trace(scoped, monkeypatch):
@@ -167,13 +183,20 @@ def test_metrics_read_the_scoped_trace(scoped, monkeypatch):
     monkeypatch.setattr(scopes, "_last", [])
     ctx = prog.ctx
     read = {m: bench.metric_reader(m)(ctx) for m in NEW_METRICS}
-    assert scopes.of(ctx).path == path
     assert read["loop.host_reads_per_step.online"] == 4
     gap = bench.metric_reader("loop.gap_ms_per_step")(ctx)
-    assert 0 < read["loop.read_ms_per_step.online"] <= gap
+    # every gap after a program is the read's; the window's first gap,
+    # before its first program, is the dispatch's
+    assert (STEPS - 1) / STEPS * gap < read["loop.read_ms_per_step.online"] \
+        <= gap
+    dispatch = [e.end - e.start
+                for e in scopes.window_spans(ctx, "repro.loop.dispatch")]
+    assert read["loop.dispatch_ms_per_step.online"] == pytest.approx(
+        1e3 * sum(dispatch) / STEPS)
+    assert 0 < read["loop.dispatch_ms_per_step.online"] < gap
     for m, scope in SCOPE_METRICS.items():
         assert read[f"{m}.train"] == read[f"{m}.online"] == pytest.approx(
             1e3 * prog.parts.get(scope, 0.0) / STEPS)
+        assert read[f"{m}.online"] >= 0
     assert read["model.embed_ms_per_step.online"] > 0
     assert read["model.layer_slice_ms_per_step.online"] > 0
-    assert read["kernels.prep_ms_per_step.online"] >= 0

@@ -384,7 +384,6 @@ def build_mgd_step(
                             impl=cfg.kernel_impl)
         return pert.Probe(n, _probe_seed(cfg, 0), ctx)
 
-    @jax.named_scope("repro.step.probe")
     def probe_once_fused(params, state, batch):
         """Fused probe → (C̃, c0, cost_metric); no θ̃ pytree exists."""
         n = state.step
@@ -406,7 +405,6 @@ def build_mgd_step(
             params, batch, _probe(n, (1.0,)), step=n, tags=(1,))[0]
         return c_pert - c0, c0, c0
 
-    @jax.named_scope("repro.step.update")
     def _fused_leaf_updates(params, lseeds_of, coefs, alpha, small_update):
         """Shared leaf walk: ndim≥2 leaves through mgd_update_window,
         small leaves through ``small_update(leaf, lid)``."""

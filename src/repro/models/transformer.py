@@ -457,12 +457,11 @@ def model_forward_perturbed(params, cfg: ArchConfig, batch, probe):
     with jax.named_scope("repro.model.layers"):
         xs, _ = jax.lax.scan(
             body, xs, (params["layers"], jnp.arange(cfg.n_layers)))
-    with jax.named_scope("repro.model.head"):
-        xs = prmsnorm(emb["ln_f"], xs, eids["ln_f"], probe, eps=cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = tuple(x @ t.T for x, t in zip(xs, tables))
-        else:
-            logits = pdense(emb["head"], xs, eids["head"], probe)
+    xs = prmsnorm(emb["ln_f"], xs, eids["ln_f"], probe, eps=cfg.norm_eps)
+    if cfg.tie_embeddings:
+        logits = tuple(x @ t.T for x, t in zip(xs, tables))
+    else:
+        logits = pdense(emb["head"], xs, eids["head"], probe)
     logits = tuple(shard(l, "batch", None, "model") for l in logits)
     if cfg.n_codebooks:
         logits = tuple(l.reshape(b, s, cfg.n_codebooks, cfg.vocab)

@@ -25,7 +25,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.profiler import StepTraceAnnotation, TraceAnnotation
+from jax.profiler import TraceAnnotation
 
 from repro.api.driver import (MGDDriver, driver as build_driver, state_step,
                               warn_deprecated)
@@ -286,10 +286,9 @@ def train_mgd(
     plant_fence = getattr(drv.plant, "fence", None)
     fence = plant_fence if callable(plant_fence) else (lambda: None)
 
-    # Host spans on the profiler's clock (recorded only while a profiler
-    # session is open): ``repro.chunk`` encloses one device program from
-    # dispatch through the host read of its metrics; the loop's phases
-    # are ``repro.loop.*``, their counters span arguments.
+    # Host spans on the profiler's clock, recorded only while a profiler
+    # session is open: each device program's dispatch and the host read
+    # of its metrics.
     runners = {}
     history = []
     done = start_step
@@ -299,38 +298,32 @@ def train_mgd(
         if recal_every:
             # stop each device program at the next recalibration boundary
             n = min(n, recal_every - done % recal_every)
-        new_program = n not in runners
-        if new_program:
+        if n not in runners:
             runners[n] = make_runner(n)
-        with StepTraceAnnotation("repro.chunk", step_num=done, steps=n):
-            with TraceAnnotation("repro.loop.dispatch",
-                                 new_program=int(new_program)):
-                params, state, metrics = runners[n](params, state)
-            with TraceAnnotation("repro.loop.read", transfers=len(metrics)):
-                rec = {k: float(v) for k, v in metrics.items()}
+        with TraceAnnotation("repro.loop.dispatch"):
+            params, state, metrics = runners[n](params, state)
+        with TraceAnnotation("repro.loop.read"):
+            rec = {k: float(v) for k, v in metrics.items()}
         done += n
         if eval_fn and eval_every and (done % eval_every < chunk):
-            with TraceAnnotation("repro.loop.eval"):
-                fence()
-                rec.update({k: float(v) for k, v in eval_fn(params).items()})
+            fence()
+            rec.update({k: float(v) for k, v in eval_fn(params).items()})
         history.append((done, rec))
         if log:
             msg = " ".join(f"{k}={v:.4g}" for k, v in rec.items())
             log(f"[mgd] step {done}/{num_steps} {msg} "
                 f"({(time.time()-t0):.1f}s)")
         if recal_every and done % recal_every == 0 and done < num_steps:
-            with TraceAnnotation("repro.loop.recalibrate"):
-                fence()
-                params = _recalibrate(drv, params, shadow, done)
+            fence()
+            params = _recalibrate(drv, params, shadow, done)
             if log:
                 log(f"[mgd] step {done}: scheduled recalibration "
                     f"(full rewrite from shadow params)")
         if checkpoint_dir and checkpoint_every and done % checkpoint_every == 0:
-            with TraceAnnotation("repro.loop.checkpoint"):
-                fence()
-                ckpt.save(checkpoint_dir, done, _ckpt_tree(params, state),
-                          extra={"algo": drv.algorithm,
-                                 "seed": int(getattr(drv.config, "seed", 0))})
+            fence()
+            ckpt.save(checkpoint_dir, done, _ckpt_tree(params, state),
+                      extra={"algo": drv.algorithm,
+                             "seed": int(getattr(drv.config, "seed", 0))})
     fence()
     # fault-tolerant plants (ExternalPlant/ChipFarm with a FaultPolicy)
     # expose a telemetry summary — surface it once so a run that survived

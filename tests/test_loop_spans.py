@@ -1,7 +1,6 @@
-"""The MGD loop's host spans and their counters, as the profiler records
-them: ``repro.chunk`` around each device program, and inside it
-``repro.loop.dispatch`` and ``repro.loop.read``; the boundaries'
-``repro.loop.eval`` and ``repro.loop.checkpoint`` after it."""
+"""The MGD loop's host spans as the profiler records them: for each device
+program, ``repro.loop.dispatch`` around its call and then
+``repro.loop.read`` around the host read of its metrics."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -10,6 +9,7 @@ import repro
 from repro.api.driver import DriverConfig
 
 STEPS, CHUNK = 6, 2
+SPANS = ("repro.loop.dispatch", "repro.loop.read")
 
 
 def _loss(p, b):
@@ -21,19 +21,22 @@ def _sample(step):
     return {"x": x, "y": x @ jnp.ones((3, 2))}
 
 
+def _train():
+    loop = repro.TrainLoopConfig(
+        chunk=CHUNK, log=None, eval_fn=lambda p: {"w_sum": jnp.sum(p["w"])},
+        eval_every=CHUNK)
+    return repro.train(_loss, {"w": jnp.zeros((3, 2), jnp.float32)},
+                       DriverConfig(dtheta=1e-2, eta=0.5), _sample, STEPS,
+                       loop=loop)
+
+
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
     """(result, [(name, start_ns, end_ns, args)] of the repro.* spans)."""
     from jax.profiler import ProfileData
     out = tmp_path_factory.mktemp("profile")
-    loop = repro.TrainLoopConfig(
-        chunk=CHUNK, log=None, eval_fn=lambda p: {"w_sum": jnp.sum(p["w"])},
-        eval_every=CHUNK, checkpoint_dir=str(out / "ckpt"),
-        checkpoint_every=CHUNK)
     with jax.profiler.trace(str(out / "trace")):
-        res = repro.train(_loss, {"w": jnp.zeros((3, 2), jnp.float32)},
-                          DriverConfig(dtheta=1e-2, eta=0.5), _sample, STEPS,
-                          loop=loop)
+        res = _train()
     path = sorted((out / "trace").rglob("*.xplane.pb"))[-1]
     spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
              for plane in ProfileData.from_file(str(path)).planes
@@ -46,44 +49,29 @@ def _named(spans, name):
     return [s for s in spans if s[0] == name]
 
 
-def test_one_chunk_span_per_program(traced):
+def test_loop_records_only_its_two_spans(traced):
     _, spans = traced
-    chunks = _named(spans, "repro.chunk")
-    assert [c[3]["step_num"] for c in chunks] == list(range(0, STEPS, CHUNK))
-    assert sum(c[3]["steps"] for c in chunks) == STEPS
+    assert {s[0] for s in spans} == set(SPANS)
+    assert all(s[3] == {} for s in spans)
 
 
-def test_dispatch_counts_new_programs(traced):
+@pytest.mark.parametrize("name", SPANS)
+def test_one_span_per_program(traced, name):
     _, spans = traced
-    assert [d[3]["new_program"] for d in
-            _named(spans, "repro.loop.dispatch")] == [1, 0, 0]
+    assert len(_named(spans, name)) == STEPS // CHUNK
 
 
-def test_read_counts_one_transfer_per_metric(traced):
-    res, spans = traced
-    metrics = set(res.history[0][1]) - {"w_sum"}     # less the eval's
-    reads = _named(spans, "repro.loop.read")
-    assert len(reads) == STEPS // CHUNK
-    assert all(r[3]["transfers"] == len(metrics) for r in reads)
-
-
-def test_loop_spans_nest_in_their_chunk(traced):
+def test_each_read_follows_its_dispatch(traced):
+    """dispatch, read, dispatch, read, ...: one after another on the
+    loop's thread, never nested."""
     _, spans = traced
-    chunks = _named(spans, "repro.chunk")
-    for name in ("repro.loop.dispatch", "repro.loop.read"):
-        inner = _named(spans, name)
-        assert len(inner) == len(chunks)
-        for c, s in zip(chunks, inner):
-            assert c[1] <= s[1] <= s[2] <= c[2], (name, c, s)
-    dispatch, read = (_named(spans, n) for n in ("repro.loop.dispatch",
-                                                "repro.loop.read"))
-    assert all(d[2] <= r[1] for d, r in zip(dispatch, read))
+    order = [s for s in spans if s[0] in SPANS]
+    assert [s[0] for s in order] == list(SPANS) * (STEPS // CHUNK)
+    assert all(a[2] <= b[1] for a, b in zip(order, order[1:]))
 
 
-@pytest.mark.parametrize("name", ["repro.loop.eval", "repro.loop.checkpoint"])
-def test_boundaries_follow_their_chunk(traced, name):
-    _, spans = traced
-    chunks, bounds = _named(spans, "repro.chunk"), _named(spans, name)
-    assert len(bounds) == len(chunks)
-    for c, b in zip(chunks, bounds):
-        assert b[1] >= c[2]
+def test_spans_change_no_result(traced):
+    res, _ = traced
+    plain = _train()
+    assert plain.history == res.history
+    assert jnp.array_equal(plain.params["w"], res.params["w"])
