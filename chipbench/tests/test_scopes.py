@@ -3,6 +3,7 @@ steps of mistral-nemo-12b (4 layers, 1x128 tokens), one step per program:
 ``online-4steps`` from a program without scopes or spans, and
 ``online-4steps-scoped`` from one with them, recorded as a ``--trace 1``
 run records its window (``python3 -m chipbench.tests.record_trace``)."""
+import dataclasses
 import gzip
 import shutil
 from pathlib import Path
@@ -166,6 +167,22 @@ def test_gaps_between_steps_are_the_loops(scoped):
     assert all(b - a > 1e-3 for a, b in between)
     for a, b in between:
         assert tr.label_gap(prog.ctx.trace.host, a, b) == "repro.loop.read"
+
+
+@pytest.mark.parametrize("shift_ms", [-1.3, -0.6, 0.6, 1.3])
+def test_read_gaps_hold_under_a_clock_offset(scoped, shift_ms):
+    """Placed ``shift_ms`` off the host's clock, as chip runs place the
+    device's ops up to ~1.2 ms early, each between-step gap is still
+    the read's whole: ``loop.read_ms_per_step`` takes whole gaps."""
+    _, prog = scoped
+    trace, shift = prog.ctx.trace, shift_ms * 1e-3
+    ops = [dataclasses.replace(e, start=e.start + shift, end=e.end + shift)
+           for e in trace.devices[0]]
+    gaps = tr.idle_gaps(ops, ops[0].start, ops[-1].end)
+    between = sorted(gaps, key=lambda g: g[0] - g[1])[:STEPS - 1]
+    assert all(b - a > 1e-3 for a, b in between)
+    for a, b in between:
+        assert tr.label_gap(trace.host, a, b) == "repro.loop.read"
 
 
 def test_trace_path_is_where_run_writes_it(scoped, monkeypatch):
