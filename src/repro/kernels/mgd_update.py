@@ -60,7 +60,7 @@ def mgd_update(
     """W − (η/Δθ)·Σ_j coefs[j]·signs_j, fused; returns the updated W.
 
     ``n_cols`` overrides the sign-indexing row stride (the unpadded N) when
-    W arrives zero-padded on its last dim — see perturbed_matmul.
+    W arrives zero-padded on its last dim — see ``_tile_index``.
     """
     kdim, n = w.shape
     bk, bn = min(bk, kdim), min(bn, n)

@@ -6,13 +6,14 @@
   TPU backend is present)
 * "ref"       — pure-jnp oracle (always available, materializes θ̃)
 
-The wrappers zero-pad non-tile-aligned shapes, so any (M, K, N) works.
-Padding is sign-safe on every dim because the kernels index signs with the
-*unpadded* row stride (``n_cols``): real elements keep their original
-row-major linear indices; padded rows multiply zero x columns and padded
-columns feed only outputs that are sliced away.  (The previous strategy —
-largest divisor ≤ cap — silently degraded to 1-wide tiles for prime dims,
-e.g. K=257 → bk=1, a catastrophic grid.)
+Any (M, K, N) works.  The probe kernels pick their tiles from the shapes
+and take ragged last M and N blocks, so neither x nor W is padded there;
+only a K that no multiple of 128 divides and that is too deep for one
+block is zero-padded.  The update wrappers zero-pad non-tile-aligned
+shapes.  Padding is sign-safe on every dim because the kernels index
+signs with the *unpadded* row stride (``n_cols``): real elements keep
+their original row-major linear indices; padded rows multiply zero x
+columns and padded columns feed only outputs that are sliced away.
 """
 from __future__ import annotations
 
@@ -24,6 +25,7 @@ import jax.numpy as jnp
 from . import ref as _ref
 from .mgd_update import mgd_update as _mgd_update_pallas
 from .mgd_update import mgd_update_window as _mgd_update_window_pallas
+from .perturbed_matmul import k_block
 from .perturbed_matmul import perturbed_matmul as _perturbed_matmul_pallas
 from .perturbed_matmul import (
     perturbed_matmul_pair as _perturbed_matmul_pair_pallas)
@@ -56,74 +58,66 @@ def _tile(dim: int, cap: int) -> int:
 
 
 def _flatten_lead(x):
-    lead = x.shape[:-1]
-    m = 1
-    for s in lead:
-        m *= s
-    return x.reshape(m, x.shape[-1]), lead, m
+    return x.reshape(-1, x.shape[-1]), x.shape[:-1]
+
+
+def _pad_k(xs, w):
+    """Zero-pad K to a multiple of 128 where the probe kernels have no
+    block for it (``k_block``): zero x columns meet the padded W rows, so
+    those rows' signs add nothing."""
+    if k_block(w.shape[0]):
+        return xs, w
+    return [_pad_to(x, 128, 1) for x in xs], _pad_to(w, 128, 0)
 
 
 def perturbed_matmul(x, w, lseed, *, dtheta, sign=1.0, impl=None,
-                     bm=128, bn=128, bk=128, out_dtype=None):
+                     out_dtype=None):
     """y = x @ (W + sign·Δθ·rademacher(lseed)); θ̃ fused in-kernel.
 
-    Leading batch dims of ``x`` are flattened into M.  Arbitrary shapes are
-    zero-padded to tile multiples; sign indexing stays anchored to the
-    unpadded W (see module docstring).
+    Leading batch dims of ``x`` are flattened into M.  Tiles are picked
+    from the shapes (``perturbed_matmul.pick_tiles``).
     """
     impl = impl or default_impl()
     if impl == "ref":
         return _ref.perturbed_matmul_ref(
             x, w, lseed, dtheta=dtheta, sign=sign, out_dtype=out_dtype)
 
-    kdim, n = w.shape
+    n = w.shape[1]
     with _prep():
-        x2, lead, m = _flatten_lead(x)
-        bm_eff = min(bm, max(8, m))
-        bk_eff = _tile(kdim, bk)
-        bn_eff = _tile(n, bn)
-        x2p = _pad_to(_pad_to(x2, bm_eff, 0), bk_eff, 1)
-        wp = _pad_to(_pad_to(w, bk_eff, 0), bn_eff, 1)
+        x2, lead = _flatten_lead(x)
+        (x2,), wk = _pad_k([x2], w)
     y = _perturbed_matmul_pallas(
-        x2p, wp, lseed, dtheta=dtheta, sign=sign,
-        bm=min(bm_eff, x2p.shape[0]), bn=bn_eff, bk=bk_eff,
-        out_dtype=out_dtype or x.dtype, n_cols=n,
-        interpret=(impl == "interpret"),
+        x2, wk, lseed, dtheta=dtheta, sign=sign,
+        out_dtype=out_dtype or x.dtype, interpret=(impl == "interpret"),
     )
     with _prep():
-        return y[:m, :n].reshape(*lead, n)
+        return y.reshape(*lead, n)
 
 
 def perturbed_matmul_pair(xp, xm, w, lseed, *, dtheta, impl=None,
-                          bm=128, bn=128, bk=128, out_dtype=None):
+                          out_dtype=None):
     """(xp @ (W+θ̃), xm @ (W−θ̃)) with ONE pass over W (antithetic probe pair).
 
     ``xp``/``xm`` are the +/− probe activation streams (same shape); leading
-    batch dims are flattened into M.
+    batch dims are flattened into M.  Tiles are picked from the shapes
+    (``perturbed_matmul.pick_tiles``).
     """
     impl = impl or default_impl()
     if impl == "ref":
         return _ref.perturbed_matmul_pair_ref(
             xp, xm, w, lseed, dtheta=dtheta, out_dtype=out_dtype)
 
-    kdim, n = w.shape
+    n = w.shape[1]
     with _prep():
-        xp2, lead, m = _flatten_lead(xp)
-        xm2, _, _ = _flatten_lead(xm)
-        bm_eff = min(bm, max(8, m))
-        bk_eff = _tile(kdim, bk)
-        bn_eff = _tile(n, bn)
-        xp2 = _pad_to(_pad_to(xp2, bm_eff, 0), bk_eff, 1)
-        xm2 = _pad_to(_pad_to(xm2, bm_eff, 0), bk_eff, 1)
-        wp = _pad_to(_pad_to(w, bk_eff, 0), bn_eff, 1)
+        xp2, lead = _flatten_lead(xp)
+        xm2, _ = _flatten_lead(xm)
+        (xp2, xm2), wk = _pad_k([xp2, xm2], w)
     yp, ym = _perturbed_matmul_pair_pallas(
-        xp2, xm2, wp, lseed, dtheta=dtheta,
-        bm=min(bm_eff, xp2.shape[0]), bn=bn_eff, bk=bk_eff,
-        out_dtype=out_dtype or xp.dtype, n_cols=n,
+        xp2, xm2, wk, lseed, dtheta=dtheta, out_dtype=out_dtype or xp.dtype,
         interpret=(impl == "interpret"),
     )
     with _prep():
-        return (yp[:m, :n].reshape(*lead, n), ym[:m, :n].reshape(*lead, n))
+        return yp.reshape(*lead, n), ym.reshape(*lead, n)
 
 
 def _as_matrix(w):

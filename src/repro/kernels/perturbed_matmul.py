@@ -14,8 +14,9 @@ implementation that materializes θ+θ̃ (measured in EXPERIMENTS.md §Perf).
 
 σ ∈ {+1, −1} selects the antithetic probe for central differences.
 
-Grid: (M/bm, N/bn, K/bk), K innermost; f32 accumulation in VMEM scratch.
-Tile defaults are MXU-aligned (128×128).
+Grid: (⌈M/bm⌉, ⌈N/bn⌉, K/bk), K innermost; f32 accumulation in VMEM
+scratch.  ``pick_tiles`` sizes the blocks from the call's shapes and a VMEM
+budget: each W tile is read, and its signs hashed, once per row block.
 """
 from __future__ import annotations
 
@@ -47,7 +48,8 @@ def _tile_index(k0, n0, bk, bn, n_cols):
     """uint32 linear indices of the W tile whose top-left element is
     (k0, n0): W[r, c] flattens row-major to r*N + c — identical to the
     ``lax.iota`` indexing of the host-side generator.  ``n_cols`` is the
-    UNPADDED row stride (see perturbed_matmul's docstring)."""
+    leaf's row stride N: a ragged last block's columns past N get indices
+    that belong to other elements, but they feed only dropped outputs."""
     # k0/n0 are traced (program_id·tile) — convert via astype, not np.uint32
     rows = (jax.lax.broadcasted_iota(jnp.uint32, (bk, bn), 0)
             + jnp.asarray(k0, jnp.int32).astype(jnp.uint32))
@@ -70,6 +72,121 @@ def _index_signs(idx, lseed):
 def _tile_signs(lseed, k0, n0, bk, bn, n_cols):
     """±1 f32 signs for the W tile whose top-left element is (k0, n0)."""
     return _index_signs(_tile_index(k0, n0, bk, bn, n_cols), lseed)
+
+
+VMEM_LIMIT = 64 * 2 ** 20
+"""Scoped VMEM the probe kernels ask Mosaic for: above its default (16 MiB
+on v5e), within every TPU core's VMEM (64 MiB on v5p and v7x, 128 MiB on
+v5e and v6e)."""
+VMEM_BUDGET = 56 * 2 ** 20
+"""What ``pick_tiles`` lets one grid step take by ``vmem_bytes``; the rest
+of ``VMEM_LIMIT`` is left to Mosaic's own scratch.  It admits (2048, 512,
+1024) at bf16, the fastest tiles a TPU v5e sweep found at 2048 rows."""
+_LANE = 128
+_SUB = 16           # row alignment: bf16's sublane tile, a multiple of f32's
+_BM_MAX = 2048      # rows per block
+_BK_MAX = 512       # contraction depth per block
+_BN_MAX = 2048      # output columns per block
+
+
+def vmem_bytes(bm, bk, bn, itemsize):
+    """VMEM one grid step of the pair kernel holds: the double-buffered
+    x± [bm, bk], W [bk, bn] and y± [bm, bn] blocks, both f32 accumulators
+    [bm, bn], and the f32 W tile temporaries (W, signs, W + θ̃, W − θ̃).
+    The single-probe kernel holds less on the same tiles."""
+    blocks = 2 * itemsize * (2 * bm * bk + bk * bn + 2 * bm * bn)
+    return blocks + 4 * (2 * bm * bn + 4 * bk * bn)
+
+
+def _rows(m, cap):
+    """Row block: all of M up to ``cap``, else the fewest ``_SUB``-aligned
+    blocks of at most ``cap``, split evenly (the last may be ragged)."""
+    if m <= cap:
+        return m
+    units = pl.cdiv(m, _SUB)
+    return _SUB * pl.cdiv(units, pl.cdiv(units, cap // _SUB))
+
+
+def _cols(n, cap):
+    """Column block: all of N up to ``cap``, else the multiple of 128 up
+    to ``cap`` that minimises the blocks' padded width plus one lane tile
+    per block (a grid step's fixed cost), the wider on a tie.  A ragged
+    last block is sized by this, not by a pad of W."""
+    if n <= cap:
+        return n
+    units = pl.cdiv(n, _LANE)
+    best = min(range(cap // _LANE, 0, -1),
+               key=lambda b: pl.cdiv(units, b) * (b + 1))
+    return _LANE * best
+
+
+def k_block(k):
+    """bk for a contraction depth K: all of K up to ``_BK_MAX``, else the
+    largest multiple of 128 up to it that divides K; None where there is
+    none, and the caller must zero-pad K (a ragged K block would add
+    garbage to every output)."""
+    if k <= _BK_MAX:
+        return k
+    return max((b for b in range(_LANE, _BK_MAX + 1, _LANE) if k % b == 0),
+               default=None)
+
+
+def pick_tiles(m, k, n, itemsize):
+    """(bm, bk, bn) for an [m, k] @ [k, n] probe kernel call.
+
+    Rows first: the fewer row blocks, the fewer times each W tile is read
+    from HBM and its signs hashed; up to ``_BM_MAX`` rows that is once.  bk
+    is ``k_block(k)``.  bn is as wide as ``VMEM_BUDGET`` then allows, its
+    cap halved down to one lane tile before the row cap is halved.  Dims
+    under the caps keep whole-dim tiles.
+    """
+    bk = k_block(k)
+    assert bk, f"K={k} needs zero-padding to a multiple of {_LANE}"
+    bm_cap = _BM_MAX
+    while True:
+        bm = _rows(m, bm_cap)
+        bn_cap = _BN_MAX
+        while bn_cap >= _LANE:
+            bn = _cols(n, bn_cap)
+            if vmem_bytes(bm, bk, bn, itemsize) <= VMEM_BUDGET:
+                return bm, bk, bn
+            bn_cap //= 2
+        assert bm_cap > _SUB, (m, k, n, itemsize)
+        bm_cap //= 2
+
+
+def _tiles(x, w):
+    """``pick_tiles`` for ``x @ w``, at the wider of their dtypes."""
+    return pick_tiles(x.shape[0], x.shape[1], w.shape[1],
+                      max(x.dtype.itemsize, w.dtype.itemsize))
+
+
+def _probe_call(kernel, xs, w, lseed, *, bm, bn, bk, out_dtype, interpret,
+                name):
+    """One pallas_call over a (⌈M/bm⌉, ⌈N/bn⌉, K/bk) grid: the x streams'
+    blocks (i, k), W's (k, j), one f32 accumulator and one output per
+    stream.  Ragged last M and N blocks read past the arrays, and only
+    outputs past them see what was read; their writeback is masked."""
+    m, kdim = xs[0].shape
+    n = w.shape[1]
+    grid = (pl.cdiv(m, bm), pl.cdiv(n, bn), kdim // bk)
+    x_spec = pl.BlockSpec((bm, bk), lambda i, j, k, *_: (i, k))
+    w_spec = pl.BlockSpec((bk, bn), lambda i, j, k, *_: (k, j))
+    o_spec = pl.BlockSpec((bm, bn), lambda i, j, k, *_: (i, j))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[x_spec] * len(xs) + [w_spec],
+            out_specs=[o_spec] * len(xs),
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)] * len(xs),
+        ),
+        out_shape=[jax.ShapeDtypeStruct((m, n), out_dtype)] * len(xs),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
+        interpret=interpret,
+        name=name,
+    )(jnp.asarray(lseed, jnp.uint32).reshape(1), *xs, w)
 
 
 def _kernel(lseed_ref, x_ref, w_ref, o_ref, acc_ref, *,
@@ -96,10 +213,7 @@ def _kernel(lseed_ref, x_ref, w_ref, o_ref, acc_ref, *,
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("dtheta", "sign", "bm", "bn", "bk", "out_dtype",
-                     "interpret", "n_cols"),
-)
+    jax.jit, static_argnames=("dtheta", "sign", "out_dtype", "interpret"))
 def perturbed_matmul(
     x: jnp.ndarray,            # [M, K]
     w: jnp.ndarray,            # [K, N]
@@ -107,52 +221,25 @@ def perturbed_matmul(
     *,
     dtheta: float,
     sign: float = 1.0,
-    bm: int = 128,
-    bn: int = 128,
-    bk: int = 128,
     out_dtype=None,
     interpret: bool = False,
-    n_cols: int | None = None,
 ) -> jnp.ndarray:
     """y = x @ (W + sign·Δθ·rademacher(lseed)) with fused sign generation.
 
-    ``n_cols`` overrides the row stride used for sign indexing — pass the
-    *unpadded* N when W has been zero-padded on its last dim so the signs of
-    the real elements keep their original linear indices (padded rows/cols
-    feed only discarded outputs or zero x columns, so their garbage signs
-    are harmless).
+    The tiles are the pair kernel's (``pick_tiles``), so one probe of a
+    pair and the single-probe call sum in the same order.
     """
-    m, kdim = x.shape
-    k2, n = w.shape
-    assert kdim == k2, (x.shape, w.shape)
-    bm, bn, bk = min(bm, m), min(bn, n), min(bk, kdim)
-    assert m % bm == 0 and n % bn == 0 and kdim % bk == 0, (
-        f"shapes ({m},{kdim})x({kdim},{n}) not divisible by tile "
-        f"({bm},{bn},{bk}); pad upstream")
-    out_dtype = out_dtype or x.dtype
-    k_tiles = kdim // bk
-
-    grid = (m // bm, n // bn, k_tiles)
+    kdim, n = w.shape
+    assert x.shape[1] == kdim, (x.shape, w.shape)
+    bm, bk, bn = _tiles(x, w)
     kernel = functools.partial(
         _kernel, dtheta=float(dtheta), sign=float(sign),
-        bk=bk, bn=bn, n_cols=n_cols or n, k_tiles=k_tiles,
+        bk=bk, bn=bn, n_cols=n, k_tiles=kdim // bk,
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((bm, bk), lambda i, j, k, *_: (i, k)),
-                pl.BlockSpec((bk, bn), lambda i, j, k, *_: (k, j)),
-            ],
-            out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, *_: (i, j)),
-            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        ),
-        out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        interpret=interpret,
-        name="perturbed_matmul",
-    )(jnp.asarray(lseed, jnp.uint32).reshape(1), x, w)
+    (y,) = _probe_call(kernel, [x], w, lseed, bm=bm, bn=bn, bk=bk,
+                       out_dtype=out_dtype or x.dtype, interpret=interpret,
+                       name="perturbed_matmul")
+    return y
 
 
 # ---------------------------------------------------------------------------
@@ -189,10 +276,7 @@ def _pair_kernel(lseed_ref, xp_ref, xm_ref, w_ref, op_ref, om_ref,
 
 
 @functools.partial(
-    jax.jit,
-    static_argnames=("dtheta", "bm", "bn", "bk", "out_dtype", "interpret",
-                     "n_cols"),
-)
+    jax.jit, static_argnames=("dtheta", "out_dtype", "interpret"))
 def perturbed_matmul_pair(
     xp: jnp.ndarray,           # [M, K] activation stream of the +θ̃ probe
     xm: jnp.ndarray,           # [M, K] activation stream of the −θ̃ probe
@@ -200,60 +284,31 @@ def perturbed_matmul_pair(
     lseed: jnp.ndarray,        # uint32 scalar
     *,
     dtheta: float,
-    bm: int = 128,
-    bn: int = 128,
-    bk: int = 128,
     out_dtype=None,
     interpret: bool = False,
-    n_cols: int | None = None,
 ):
     """(xp @ (W+θ̃), xm @ (W−θ̃)) in ONE grid pass over W.
 
     The central-difference probe pair of MGD shares one HBM read of each W
-    tile: the tile is loaded for the MXU once, the ±Δθ sign pattern is
-    regenerated in VMEM, and both antithetic products accumulate in separate
-    scratch.  Per probe *pair* the weight-read traffic is therefore 1× the
-    inference bytes (vs 2× for two independent fused calls and ~4× for the
-    materializing baseline — see EXPERIMENTS.md §Perf).
+    tile per row block: the tile is loaded for the MXU once, the ±Δθ sign
+    pattern is regenerated in VMEM, and both antithetic products accumulate
+    in separate scratch.  With all M rows in one block (``pick_tiles``),
+    the weight-read traffic per probe *pair* is 1× the inference bytes (vs
+    2× for two independent fused calls and ~4× for the materializing
+    baseline — see EXPERIMENTS.md §Perf).
 
     ``xp`` and ``xm`` are the two activation streams (identical at the input
     layer, diverging after the first perturbed layer).
     """
-    m, kdim = xp.shape
     assert xm.shape == xp.shape, (xp.shape, xm.shape)
-    k2, n = w.shape
-    assert kdim == k2, (xp.shape, w.shape)
-    bm, bn, bk = min(bm, m), min(bn, n), min(bk, kdim)
-    assert m % bm == 0 and n % bn == 0 and kdim % bk == 0, (
-        f"shapes ({m},{kdim})x({kdim},{n}) not divisible by tile "
-        f"({bm},{bn},{bk}); pad upstream")
-    out_dtype = out_dtype or xp.dtype
-    k_tiles = kdim // bk
-
-    grid = (m // bm, n // bn, k_tiles)
+    kdim, n = w.shape
+    assert xp.shape[1] == kdim, (xp.shape, w.shape)
+    bm, bk, bn = _tiles(xp, w)
     kernel = functools.partial(
         _pair_kernel, dtheta=float(dtheta),
-        bk=bk, bn=bn, n_cols=n_cols or n, k_tiles=k_tiles,
+        bk=bk, bn=bn, n_cols=n, k_tiles=kdim // bk,
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((bm, bk), lambda i, j, k, *_: (i, k)),
-                pl.BlockSpec((bm, bk), lambda i, j, k, *_: (i, k)),
-                pl.BlockSpec((bk, bn), lambda i, j, k, *_: (k, j)),
-            ],
-            out_specs=[
-                pl.BlockSpec((bm, bn), lambda i, j, k, *_: (i, j)),
-                pl.BlockSpec((bm, bn), lambda i, j, k, *_: (i, j)),
-            ],
-            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32),
-                            pltpu.VMEM((bm, bn), jnp.float32)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((m, n), out_dtype),
-                   jax.ShapeDtypeStruct((m, n), out_dtype)],
-        interpret=interpret,
-        name="perturbed_matmul_pair",
-    )(jnp.asarray(lseed, jnp.uint32).reshape(1), xp, xm, w)
+    return tuple(_probe_call(
+        kernel, [xp, xm], w, lseed, bm=bm, bn=bn, bk=bk,
+        out_dtype=out_dtype or xp.dtype, interpret=interpret,
+        name="perturbed_matmul_pair"))

@@ -61,33 +61,41 @@ def _compile(fn, *args):
 
 
 KERNELS = {
-    "perturbed_matmul_pair": lambda s, n: (
+    "perturbed_matmul_pair": lambda s, m, n: (
         lambda xp, xm, w, l: ops.perturbed_matmul_pair(
             xp, xm, w, l, dtheta=2 ** -6, impl="pallas"),
-        [s((2048, 5120), BF16), s((2048, 5120), BF16), s((5120, n), BF16),
+        [s((m, 5120), BF16), s((m, 5120), BF16), s((5120, n), BF16),
          s((), jnp.uint32)]),
-    "perturbed_matmul": lambda s, n: (
+    "perturbed_matmul": lambda s, m, n: (
         lambda x, w, l: ops.perturbed_matmul(
             x, w, l, dtheta=2 ** -6, sign=-1.0, impl="pallas"),
-        [s((2048, 5120), BF16), s((5120, n), BF16), s((), jnp.uint32)]),
-    "mgd_update_window": lambda s, n: (
+        [s((m, 5120), BF16), s((5120, n), BF16), s((), jnp.uint32)]),
+    "mgd_update_window": lambda s, m, n: (
         lambda w, l, c: ops.mgd_update_window(
             w, l, c, alpha=-2e-4, dtheta=2 ** -6, impl="pallas"),
         [s((5120, n), BF16), s((1,), jnp.uint32), s((1,), jnp.float32)]),
-    "mgd_update": lambda s, n: (
+    "mgd_update": lambda s, m, n: (
         lambda w, l, c: ops.mgd_update(
             w, l, c, eta=2e-4, dtheta=2 ** -6, impl="pallas"),
         [s((5120, n), BF16), s((4,), jnp.uint32), s((4,), jnp.float32)]),
 }
 
 
-@pytest.mark.parametrize("n", [5120, 17408], ids=["attn_5120", "mlp_17408"])
+@pytest.mark.parametrize(
+    "m,n", [(2048, 5120), (2048, 17408), (2048, 151936), (128, 131072),
+            (8, 5120)],
+    ids=["attn_5120", "mlp_17408", "head_151936_m2048", "head_131072_m128",
+         "attn_5120_m8"])
 @pytest.mark.parametrize("kernel", sorted(KERNELS))
-def test_kernel_compiles_at_qwen3_14b_widths(one_chip, kernel, n):
+def test_kernel_compiles_at_qwen3_14b_widths(one_chip, kernel, m, n):
+    """Each kernel at a weight's width, the probe kernels at m rows of
+    tokens: the train cell's 2048 (qwen3-14b's head too), the online
+    cell's 128 at mistral-nemo-12b's head, and a decode-sized 8.  The
+    probe kernels' picked tiles must fit the chip's VMEM."""
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
-    fn, args = KERNELS[kernel](shape, n)
+    fn, args = KERNELS[kernel](shape, m, n)
     _compile(fn, *args)
 
 

@@ -1,11 +1,14 @@
 """Pallas kernel correctness: interpret-mode vs pure-jnp oracle, swept over
 shapes and dtypes, plus bit-exactness against the host perturbation
 generator (the contract that lets the kernel regenerate θ̃ in VMEM)."""
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import get_config
 from repro.core import perturbations as pert
 from repro.kernels import ops, ref
 
@@ -96,3 +99,69 @@ def test_batched_leading_dims():
     y_ref = ref.perturbed_matmul_ref(x.reshape(10, 64), w, ls, dtheta=0.01)
     np.testing.assert_allclose(np.asarray(y.reshape(10, 32)),
                                np.asarray(y_ref), rtol=1e-4, atol=1e-4)
+
+
+# --- tile picking ------------------------------------------------------------
+
+pm = importlib.import_module("repro.kernels.perturbed_matmul")
+
+
+def _weight_matmuls(name):
+    """(K, N) of every weight product of one forward of ``name``."""
+    c = get_config(name)
+    q, kv = c.n_heads * c.d_head, c.n_kv_heads * c.d_head
+    return [(c.d_model, q), (c.d_model, kv), (q, c.d_model),
+            (c.d_model, c.d_ff), (c.d_ff, c.d_model), (c.d_model, c.vocab)]
+
+
+CELL_SHAPES = sorted({(m, k, n) for m in (2048, 128, 8)
+                      for name in ("qwen3-14b", "mistral-nemo-12b")
+                      for k, n in _weight_matmuls(name)})
+
+
+@pytest.mark.parametrize("m,k,n", CELL_SHAPES)
+def test_pick_tiles_at_model_widths(m, k, n):
+    """Tiles of the probe kernels at the benchmark models' widths (train
+    rows 2048, online 128, decode-sized 8): K is never split raggedly nor
+    padded, W is not padded along N, one grid step fits the VMEM budget,
+    and each W tile is read and hashed at most 4 times at 2048 rows."""
+    bm, bk, bn = pm.pick_tiles(m, k, n, 2)
+    assert k % bk == 0 and bk % 128 == 0
+    assert bn % 128 == 0 or bn == n
+    assert bm % 16 == 0 or bm == m
+    assert bn >= 512 or bn == n
+    assert pm.vmem_bytes(bm, bk, bn, 2) <= pm.VMEM_BUDGET <= pm.VMEM_LIMIT
+    w = jax.ShapeDtypeStruct((k, n), jnp.bfloat16)
+    assert ops._pad_k([], w)[1] is w
+    if m == 2048:
+        assert -(-m // bm) <= 4
+
+
+@pytest.mark.parametrize("m,k,n", [(8, 8, 8), (64, 128, 128), (5, 127, 96),
+                                   (128, 64, 10)])
+def test_pick_tiles_keeps_small_dims_whole(m, k, n):
+    """The paper-scale MLPs' dims (≤ 128) keep whole-dim tiles: one grid
+    step, as before tiles were picked."""
+    assert pm.pick_tiles(m, k, n, 4) == (m, k, n)
+
+
+@pytest.mark.parametrize("m,k,n", [(2050, 256, 128 * 17),
+                                   (2071, 1024, 128 * 19)])
+def test_pair_ragged_last_blocks_match_ref(m, k, n):
+    """Past 2048 rows and 2048 columns the picked tiles leave a ragged last
+    row block and column block: the kernel reads past x and W there and
+    must drop what it computed from that on writeback, and the columns of
+    the second block must take their signs at the leaf's row stride."""
+    bm, bk, bn = pm.pick_tiles(m, k, n, 4)
+    assert m % bm and n % bn and n > bn
+    xp = jax.random.normal(jax.random.PRNGKey(0), (m, k), jnp.float32)
+    xm = jax.random.normal(jax.random.PRNGKey(1), (m, k), jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(2), (k, n), jnp.float32) * 0.1
+    ls = pert.leaf_seed(4, 9, 1)
+    yp, ym = ops.perturbed_matmul_pair(xp, xm, w, ls, dtheta=0.05,
+                                       impl="interpret")
+    rp, rm = ref.perturbed_matmul_pair_ref(xp, xm, w, ls, dtheta=0.05)
+    np.testing.assert_allclose(np.asarray(yp), np.asarray(rp),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(ym), np.asarray(rm),
+                               rtol=1e-4, atol=1e-4)
