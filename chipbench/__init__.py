@@ -5,6 +5,7 @@
 one JSON line.  Everything a cell needs is found by name: its model
 configuration in ``configs/``, its traffic mix in ``traffic/``, its
 per-layer metric readers in ``metrics/``, its correctness limits in
-``limits/`` and the plain reference of its architecture in
-``references/``.
+``limits/``, and, under the architecture its configuration names, the
+plain reference in ``references/`` and the build of the program's model
+configuration in ``programs/``.
 """

@@ -2,10 +2,13 @@
 
 A cell names a configuration and a traffic mix; each is a JSON file of
 its own (``configs/<config>.json``, ``traffic/<traffic>.json``).  Its
-correctness limits are ``limits/<cell>.json``, its architecture's plain
-reference ``references/<reference>.py``, and each per-layer metric that
-lists the cell (or lists no cells) is read by ``metrics/<metric>.py``.
-Nothing here names a cell: a new cell is new files and new entries.
+correctness limits are ``limits/<cell>.json``; the configuration's
+``reference`` key names its architecture, whose plain reference is
+``references/<reference>.py`` and whose program is built by
+``programs/<reference>.py``; each per-layer metric that lists the cell
+(or lists no cells) is read by ``metrics/<metric>.py``.  Nothing here
+names a cell or an architecture: a new cell, or a new architecture, is
+new files and new entries.
 """
 from __future__ import annotations
 
@@ -66,6 +69,12 @@ def resolve(workload: str, root: Path = ROOT) -> Cell:
 def reference(config: dict):
     """The plain reference module of a configuration's architecture."""
     return importlib.import_module(f"chipbench.references.{config['reference']}")
+
+
+def program(config: dict):
+    """The module that builds the program for a configuration's
+    architecture (``arch_config(config)``)."""
+    return importlib.import_module(f"chipbench.programs.{config['reference']}")
 
 
 def metric_reader(name: str, base: Path = HERE):

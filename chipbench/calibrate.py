@@ -12,7 +12,9 @@ planted, each compared likewise — the upper readings:
 
 * ``control_fp8``: every product of the reference in float8 e4m3, the
   precision below the configuration's bfloat16;
-* ``half_batch``: the cost taken over the first half of the batch only.
+* ``half_batch``: the cost taken over the first half of the batch only;
+* ``missing_pod`` (mixes with ``pods`` > 1 only): pod 1's C̃ left out of
+  the update.
 
 A step that returns its state unchanged reads change_gap = 1 with no run.
 The benchmark's own runs never run this.
@@ -37,16 +39,19 @@ import jax  # noqa: E402
 FAULTS = {
     "control_fp8": {"compute": "float8_e4m3fn"},
     "half_batch": {"token_frac": 0.5},
+    "missing_pod": {"drop_pod": 1},
 }
 
 
-def calibrate(cell, seeds, control_seeds, *, kernel_impl="pallas",
+def calibrate(cell, seeds, control_seeds, devices, *, kernel_impl="pallas",
               log=print) -> dict:
     out = {"cell": cell.name, "sound": {}, "faults": {}}
+    faults = {name: kw for name, kw in FAULTS.items()
+              if "drop_pod" not in kw or check.pods(cell.traffic) > 1}
     for seed in seeds:
         t0 = time.perf_counter()
         sd = run.derive_seeds(seed)
-        s = run.setup(cell, sd, kernel_impl=kernel_impl)
+        s = run.setup(cell, sd, devices, kernel_impl=kernel_impl)
         program = s.program
         del s
         ref = check.follow(cell, sd)
@@ -56,7 +61,7 @@ def calibrate(cell, seeds, control_seeds, *, kernel_impl="pallas",
             f"program {program.cost} {program.applied} reference {ref.cost} "
             f"{ref.applied} ({time.perf_counter() - t0:.1f} s)")
         if seed in control_seeds:
-            for name, kw in FAULTS.items():
+            for name, kw in faults.items():
                 bad = check.follow(cell, sd, **kw)
                 v = {k: x["value"] for k, x in
                      check.numbers(cell, bad, ref).items()}
@@ -75,7 +80,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     cell = bench.resolve(args.workload)
     try:
-        run.tpu_devices(cell.chips)
+        devices = run.tpu_devices(cell.chips)
     except run.NoChip as e:
         print(f"calibrate: {e}", file=sys.stderr)
         return 2
@@ -83,7 +88,7 @@ def main(argv=None) -> int:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     seeds = [int(x) for x in args.seeds.split(",")]
     ctl = [int(x) for x in args.control_seeds.split(",") if x]
-    res = calibrate(cell, seeds, set(ctl))
+    res = calibrate(cell, seeds, set(ctl), devices)
     text = json.dumps(res, indent=1)
     if args.out:
         Path(args.out).parent.mkdir(parents=True, exist_ok=True)

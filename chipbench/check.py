@@ -23,6 +23,18 @@ are compared, each against its limit in ``limits/<cell>.json``:
   reference, over the larger of the reference's norm of that leaf and of
   the median leaf; the worst leaf counts.  A leaf whose reference
   gradient norm is under a thousandth of the median leaf's is left out.
+
+A traffic mix with ``pods`` k > 1 runs probe-parallel MGD over a mesh of
+k chips: pod i takes rows [i·b/k, (i+1)·b/k) of every step's batch of b
+rows, probes with its own seed (``mgd.pod_seed``) and every pod applies
+all k C̃_i, each divided by k.  The reference replays every pod.  The
+program's loop reports the cost of pod 0 (``REPORTED_POD``: the mesh
+step's cost leaves its ``shard_map`` as one replicated value, and a
+replicated value is read from the mesh's first device), so
+``loss_gap_nats`` compares that pod's; ``grad_gap_nats`` compares every
+pod's C̃_i at every step, read back by projecting θ_K − θ_0 on that pod's
+signs, where it is −(η/Δθ)·C̃_i/k per element; ``change_gap`` is as
+above.  Without ``pods``, k = 1.
 """
 from __future__ import annotations
 
@@ -35,6 +47,13 @@ import numpy as np
 
 from . import textgen
 from .references import mgd
+
+REPORTED_POD = 0
+
+
+def pods(traffic: dict) -> int:
+    """Pods of the probe-parallel mesh, one per chip; 1 without a mesh."""
+    return int(traffic.get("pods", 1))
 
 
 def check_steps(traffic: dict) -> int:
@@ -53,11 +72,11 @@ def observed_steps(traffic: dict) -> list:
 @dataclasses.dataclass
 class Run:
     """What one side (program or reference) produced over the first K
-    steps: the reported cost at the observed steps, the C̃_n each step
-    applied, read back from the weights, and the per-leaf norm of
-    θ_K − θ_0."""
-    cost: dict          # step -> cost
-    applied: list       # C̃_n, n < K, in nats
+    steps: the reported cost at the observed steps, the C̃ of each step
+    and pod that the update applied, read back from the weights, and the
+    per-leaf norm of θ_K − θ_0."""
+    cost: dict          # step -> cost of the reported pod
+    applied: list       # C̃ of step n, pod i at n·k + i, n < K, in nats
     change: dict        # leaf path -> ‖θ_K − θ_0‖
 
 
@@ -73,44 +92,55 @@ def _change_stats(leaf, theta0, lseeds):
 
 def change_stats(ref_mod, arch, params: dict, seeds: dict,
                  traffic: dict) -> tuple:
-    """(per-leaf ‖θ_K − θ_0‖, [C̃_n for n < K]) of weights after the first
-    K steps; θ_0 is drawn again from the seed one leaf at a time."""
-    steps = check_steps(traffic)
+    """(per-leaf ‖θ_K − θ_0‖, [C̃ of step n, pod i for n < K, i < k]) of
+    weights after the first K steps; θ_0 is drawn again from the seed one
+    leaf at a time."""
+    steps, k = check_steps(traffic), pods(traffic)
+    probe_seeds = [mgd.pod_seed(seeds["mgd"], i) for i in range(k)]
     ids = ref_mod.leaf_ids(arch)
-    norms, proj, size = {}, np.zeros(steps), 0
+    norms, proj, size = {}, np.zeros(steps * k), 0
     for path, leaf in params.items():
         theta0 = ref_mod.init_leaf(arch, path, seeds["init"])
-        lseeds = jnp.stack([mgd.leaf_seed(seeds["mgd"], n, ids[path])
-                            for n in range(steps)])
+        lseeds = jnp.stack([mgd.leaf_seed(s, n, ids[path])
+                            for n in range(steps) for s in probe_seeds])
         norm, p = _change_stats(leaf, theta0, lseeds)
         norms[path] = float(norm)
         proj += np.asarray(p, np.float64)
         size += leaf.size
-    scale = -float(traffic["dtheta"]) / float(traffic["eta"]) / size
+    scale = -float(traffic["dtheta"]) / float(traffic["eta"]) * k / size
     return norms, [float(x) for x in proj * scale]
 
 
-def follow(cell, seeds, *, compute="float32", token_frac=1.0) -> Run:
-    """The reference's own run of the first K steps.
+def follow(cell, seeds, *, compute="float32", token_frac=1.0,
+           drop_pod=None) -> Run:
+    """The reference's own run of the first K steps, every pod in turn.
 
-    ``compute`` and ``token_frac`` turn it into the control or a planted
-    fault."""
+    ``compute``, ``token_frac`` and ``drop_pod`` (a pod whose C̃ the update
+    leaves out) turn it into the control or a planted fault."""
     from .bench import reference
     ref = reference(cell.config)
     arch = ref.Arch.from_config(cell.config)
     t = cell.traffic
     dtheta, eta = float(t["dtheta"]), float(t["eta"])
+    k = pods(t)
+    rows = int(t["batch"]) // k
+    probe_seeds = [mgd.pod_seed(seeds["mgd"], i) for i in range(k)]
     ids = ref.leaf_ids(arch)
     params = ref.init(arch, seeds["init"])
     cost = {}
     for n in range(check_steps(t)):
         b = textgen.batch(seeds["data"], n, int(t["batch"]), int(t["seq_len"]),
                           arch.vocab)
-        cp, cm = (float(c) for c in ref.costs(
-            arch, params, b["tokens"], b["labels"], seeds["mgd"], n,
-            dtheta=dtheta, compute=compute, token_frac=token_frac))
-        cost[n] = 0.5 * (cp + cm)
-        params = mgd.update(params, ids, seeds["mgd"], n, 0.5 * (cp - cm),
+        c_tildes = []
+        for i, probe_seed in enumerate(probe_seeds):
+            own = slice(i * rows, (i + 1) * rows)
+            cp, cm = (float(c) for c in ref.costs(
+                arch, params, b["tokens"][own], b["labels"][own], probe_seed,
+                n, dtheta=dtheta, compute=compute, token_frac=token_frac))
+            if i == REPORTED_POD:
+                cost[n] = 0.5 * (cp + cm)
+            c_tildes.append(0.0 if i == drop_pod else 0.5 * (cp - cm))
+        params = mgd.update(params, ids, probe_seeds, n, c_tildes,
                             eta=eta, dtheta=dtheta)
     change, applied = change_stats(ref, arch, params, seeds, t)
     return Run(cost, applied, change)

@@ -7,6 +7,10 @@ activation streams read and both outputs written; the update needs W read
 and written once in the weights' dtype.  Model FLOPs are those of the
 two probe forwards: weight products and causal attention (MGD has no
 backward pass; the update is not counted).
+
+Its functions are a dense GQA decoder's (the ``dense_gqa`` architecture);
+a new architecture brings its counts and readers as new files, and its
+readers use ``Context.roofline(kernel, calls)``, which is generic.
 """
 from __future__ import annotations
 

@@ -10,6 +10,15 @@ linear index i), reads the two costs c± = C(θ ± Δθ·s), and applies
 rounding once to the parameters' dtype.  The hash is part of the
 algorithm's definition (it is how a chip regenerates its perturbation
 instead of storing it), so it is restated here bit for bit.
+
+Over a mesh of k pods (the paper's multiplexing across hardware), pod i
+draws its own perturbation from its own probe seed (``pod_seed``) on its
+own rows and reads its own C̃_i; every pod then applies all k:
+
+    θ ← θ − η · Σ_i (C̃_i / k) · s_i / Δθ,
+
+summed in pod order in float32 and rounded once.  With k = 1 this is the
+single-probe step above.
 """
 from __future__ import annotations
 
@@ -53,17 +62,30 @@ def slice_signs(lseed, shape, offset):
     return signs(lseed, idx).reshape(shape)
 
 
+def pod_seed(probe_seed: int, pod: int) -> int:
+    """Probe seed of pod ``pod``: (seed + pod·0x9E3779B9) mod 2**32, so pod
+    0 keeps the run's seed."""
+    return (int(probe_seed) + int(pod) * int(GOLDEN)) % 2 ** 32
+
+
 @jax.jit
-def _update_leaf(leaf, lseed, coef):
-    """leaf + coef·s in float32, rounded once to leaf.dtype."""
+def _update_leaf(leaf, lseeds, coefs):
+    """leaf + Σ_i coefs[i]·s_i in float32, in pod order, rounded once to
+    leaf.dtype."""
     idx = jax.lax.iota(jnp.uint32, leaf.size).reshape(leaf.shape)
-    return (leaf.astype(jnp.float32) + coef * signs(lseed, idx)
-            ).astype(leaf.dtype)
+    acc = leaf.astype(jnp.float32)
+    for i in range(coefs.shape[0]):
+        acc = acc + coefs[i] * signs(lseeds[i], idx)
+    return acc.astype(leaf.dtype)
 
 
-def update(params, leaf_ids, probe_seed, step, c_tilde, *, eta, dtheta):
-    """One MGD update of every leaf from C̃ (a host float)."""
-    coef = jnp.float32(-eta * c_tilde / dtheta)
-    return {path: _update_leaf(leaf, leaf_seed(probe_seed, step,
-                                               leaf_ids[path]), coef)
-            for path, leaf in params.items()}
+def update(params, leaf_ids, probe_seeds, step, c_tildes, *, eta, dtheta):
+    """One MGD update of every leaf from each pod's probe seed and C̃ (host
+    floats, one per pod)."""
+    k = len(c_tildes)
+    coefs = jnp.asarray([-eta * c / dtheta / k for c in c_tildes],
+                        jnp.float32)
+    return {path: _update_leaf(
+        leaf, jnp.stack([leaf_seed(s, step, leaf_ids[path])
+                         for s in probe_seeds]), coefs)
+        for path, leaf in params.items()}

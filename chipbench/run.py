@@ -4,10 +4,15 @@
     python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (BENCHMARK.json) names a configuration and a traffic mix.  The
-run makes the weights on the device from the seed (``model_init``, one
-jitted call) and trains them with fused central-pair MGD through the
-program's own loop, ``repro.train``, fed by ``textgen`` rows that are a
-pure function of (seed, step):
+configuration's program module (``programs/<reference>.py``) builds the
+program's model configuration.  The run makes the weights on the device
+from the seed (``model_init``, one jitted call) and trains them with
+fused central-pair MGD through the program's own loop, ``repro.train``,
+fed by ``textgen`` rows that are a pure function of (seed, step).  A mix
+with ``pods`` k > 1 trains probe-parallel over a mesh of the cell's k
+chips (``repro.driver("probe_parallel")``, one probe pair per chip on its
+share of the batch, the weights replicated); without it, one chip runs
+the ``"discrete"`` driver.
 
 1. set-up: a first ``repro.train`` call compiles the step (or loads it
    from the compile cache), runs the steps the correctness check
@@ -35,6 +40,7 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import dataclasses  # noqa: E402
+import functools  # noqa: E402
 import gc  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
@@ -53,9 +59,9 @@ if str(ROOT / "src") not in sys.path:
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
+from jax.sharding import Mesh, NamedSharding, PartitionSpec  # noqa: E402
 
 import repro  # noqa: E402
-from repro.configs import get_config  # noqa: E402
 from repro.launch.cache import use_compile_cache  # noqa: E402
 from repro.models import (make_transformer_probe_fn, model_init,  # noqa: E402
                           model_loss)
@@ -88,18 +94,6 @@ def derive_seeds(seed: int) -> dict:
     return {k: int(w) % 2 ** 31 for k, w in zip(("init", "data", "mgd"), words)}
 
 
-def arch_config(c: dict):
-    """The program's ArchConfig, every size taken from the file."""
-    return get_config(c["registry"]).replace(
-        n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
-        n_heads=c["num_attention_heads"], n_kv_heads=c["num_key_value_heads"],
-        d_head=c["head_dim"], d_ff=c["intermediate_size"],
-        vocab=c["vocab_size"], norm_eps=float(c["rms_norm_eps"]),
-        rope_theta=float(c["rope_theta"]), qk_norm=c["model_type"] == "qwen3",
-        qkv_bias=bool(c.get("attention_bias", False)),
-        tie_embeddings=bool(c["tie_word_embeddings"]), dtype=c["torch_dtype"])
-
-
 def annotate(name: str):
     return jax.profiler.TraceAnnotation("chipbench." + name)
 
@@ -121,9 +115,10 @@ class Stamps:
 
 
 def flat_params(params) -> dict:
-    """path -> leaf, the paths as the reference names them."""
-    return {"/".join(k.key for k in path): leaf for path, leaf in
-            jax.tree_util.tree_flatten_with_path(params)[0]}
+    """path -> leaf on the first device (a mesh keeps every leaf whole on
+    each chip), the paths as the reference names them."""
+    return {"/".join(k.key for k in path): leaf.addressable_data(0)
+            for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]}
 
 
 def host_counters() -> tuple:
@@ -182,25 +177,40 @@ class Setup:
     marks: dict             # host clock after each part of set-up
 
 
-def setup(cell, seeds: dict, *, kernel_impl: str = "pallas",
+def setup(cell, seeds: dict, devices, *, kernel_impl: str = "pallas",
           probe_fn=None) -> Setup:
     """Weights from the seed, the driver, and a first ``repro.train`` call
     over the checked steps plus one clean chunk to time."""
     t = cell.traffic
     chunk = int(t["chunk"])
-    cfg = arch_config(cell.config)
+    cfg = bench.program(cell.config).arch_config(cell.config)
     ref = bench.reference(cell.config)
     arch = ref.Arch.from_config(cell.config)
+    pods = check.pods(t)
     init = jax.jit(model_init, static_argnums=0)
+    driver, mesh = functools.partial(repro.driver, "discrete"), None
+    if pods > 1:
+        if pods != cell.chips:
+            raise ValueError(f"{cell.name}: {pods} pods on {cell.chips} "
+                             f"chips; the mesh has one pod per chip")
+        mesh = Mesh(np.array(devices[:pods]), ("pod",))
+        everywhere = NamedSharding(mesh, PartitionSpec())
+        init = jax.jit(model_init, static_argnums=0, out_shardings=everywhere)
+        driver = functools.partial(repro.driver, "probe_parallel", mesh=mesh)
     box = [init(cfg, jax.random.PRNGKey(seeds["init"]))]
     jax.block_until_ready(box[0])
     marks = {"weights": time.perf_counter()}
     dcfg = repro.DriverConfig(
         fused=True, mode=t["mode"], kernel_impl=kernel_impl,
         dtheta=float(t["dtheta"]), eta=float(t["eta"]), seed=seeds["mgd"])
-    drv = repro.driver(
-        "discrete", dcfg, lambda p, b: model_loss(p, cfg, b),
-        probe_fn=probe_fn or make_transformer_probe_fn(cfg))
+    drv = driver(dcfg, lambda p, b: model_loss(p, cfg, b),
+                 probe_fn=probe_fn or make_transformer_probe_fn(cfg))
+    if mesh is not None:
+        # the loop's state starts on the mesh, where each device program
+        # leaves it, so every chunk runs the first chunk's program
+        first = drv.init
+        drv = drv._replace(
+            init=lambda p: jax.device_put(first(p), everywhere))
     sample_fn = textgen.sampler(seeds["data"], int(t["batch"]),
                                 int(t["seq_len"]), cfg.vocab)
     k = check.check_steps(t)
@@ -241,7 +251,8 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, devices, *,
     t = cell.traffic
     chunk = int(t["chunk"])
     with annotate("setup"):
-        s = setup(cell, seeds, kernel_impl=kernel_impl, probe_fn=probe_fn)
+        s = setup(cell, seeds, devices, kernel_impl=kernel_impl,
+                  probe_fn=probe_fn)
         drv, sample_fn, program, chunk_s, marks = (
             s.drv, s.sample_fn, s.program, s.chunk_s, s.marks)
         box = [s.params]
@@ -338,13 +349,14 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, devices, *,
           f"context switches", file=sys.stderr)
 
     # (4) correctness, with the program's state freed
+    t_ref = time.perf_counter()
     reference = check.follow(cell, seeds)
     nums = check.numbers(cell, program, reference)
     result["correct"] = check.passed(nums) and result["failed"] == 0
     result["checks"] = nums
     print(f"[chipbench] program cost {program.cost} C̃ {program.applied}; "
-          f"reference cost {reference.cost} C̃ {reference.applied}",
-          file=sys.stderr)
+          f"reference cost {reference.cost} C̃ {reference.applied} "
+          f"({time.perf_counter() - t_ref:.3f} s)", file=sys.stderr)
     order = ["correct", "attempted", "failed", "metrics", "device",
              "breakdown", "checks"]
     return {key: result[key] for key in order if key in result}

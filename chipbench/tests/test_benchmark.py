@@ -7,7 +7,7 @@ import shutil
 
 import pytest
 
-from chipbench import bench
+from chipbench import bench, check
 
 BENCH = bench.load_benchmark()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -82,6 +82,8 @@ def test_every_cell_resolves(cell):
         c.traffic)
     assert set(c.limits) >= {"loss_gap_nats", "grad_gap_nats", "change_gap"}
     assert bench.reference(c.config).Arch.from_config(c.config)
+    pods = check.pods(c.traffic)
+    assert pods in (1, c.chips) and c.traffic["batch"] % pods == 0
     assert "setup_s" in [m["name"] for m in c.end_to_end]
     assert len(c.end_to_end) >= 2 and c.per_layer
     for m in c.per_layer:

@@ -50,8 +50,10 @@ def test_state_left_unchanged(monkeypatch):
 
 def test_half_of_the_batch_left_out():
     from repro.models import make_transformer_probe_fn
+
+    from chipbench.programs.dense_gqa import arch_config
     cell = tiny_cell("qwen3-14b")
-    real = make_transformer_probe_fn(run.arch_config(cell.config))
+    real = make_transformer_probe_fn(arch_config(cell.config))
 
     def half(params, batch, probe):
         rows = batch["tokens"].shape[0] // 2

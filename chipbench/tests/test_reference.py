@@ -17,7 +17,7 @@ from repro.models import make_transformer_probe_fn, model_init  # noqa: E402
 
 @pytest.mark.parametrize("config", ["qwen3-14b", "mistral-nemo-12b"])
 def test_init_and_costs_match_the_program(config):
-    from chipbench.run import arch_config
+    from chipbench.programs.dense_gqa import arch_config
     cell = tiny_cell(config)
     cfg = arch_config(cell.config)
     arch = R.Arch.from_config(cell.config)
