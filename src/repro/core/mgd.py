@@ -258,7 +258,9 @@ def build_mgd_step(
 
     With ``cfg.fused=True`` the model additionally provides
     ``probe_fn(params, batch, probe: perturbations.Probe) -> [n_signs]``
-    costs under θ ± θ̃ — the perturbed-apply interface (e.g.
+    costs under θ ± θ̃, or ``(costs, aux)`` where the model counts
+    something beside them (``aux`` joins the step's metrics) — the
+    perturbed-apply interface (e.g.
     ``models.simple.make_mlp_probe_fn`` or
     ``models.make_transformer_probe_fn``) that routes weight matmuls
     through the Pallas kernels so θ̃ never exists in HBM.  The fused path
@@ -385,15 +387,16 @@ def build_mgd_step(
         return pert.Probe(n, _probe_seed(cfg, 0), ctx)
 
     def probe_once_fused(params, state, batch):
-        """Fused probe → (C̃, c0, cost_metric); no θ̃ pytree exists."""
+        """Fused probe → (C̃, c0, cost_metric, aux); no θ̃ pytree exists.
+        ``aux`` is what the model's probe_fn counts beside the costs."""
         n = state.step
         if cfg.mode == "central":
-            costs = plant.apply_perturbed(
+            costs, aux = plant.apply_perturbed(
                 params, batch, _probe(n, (1.0, -1.0)), step=n, tags=(0, 1))
             c_plus, c_minus = costs[0], costs[1]
             # same rounding barrier as the materialized probe_once
             c_tilde = jax.lax.optimization_barrier(0.5 * (c_plus - c_minus))
-            return c_tilde, state.c0, 0.5 * (c_plus + c_minus)
+            return c_tilde, state.c0, 0.5 * (c_plus + c_minus), aux
         need_c0 = jnp.logical_or(n % cfg.tau_x == 0, n % cfg.tau_theta == 0)
         c0 = jax.lax.cond(
             need_c0,
@@ -401,9 +404,9 @@ def build_mgd_step(
                                     tag=0).astype(jnp.float32),
             lambda: state.c0,
         )
-        c_pert = plant.apply_perturbed(
-            params, batch, _probe(n, (1.0,)), step=n, tags=(1,))[0]
-        return c_pert - c0, c0, c0
+        costs, aux = plant.apply_perturbed(
+            params, batch, _probe(n, (1.0,)), step=n, tags=(1,))
+        return costs[0] - c0, c0, c0, aux
 
     def _fused_leaf_updates(params, lseeds_of, coefs, alpha, small_update):
         """Shared leaf walk: ndim≥2 leaves through mgd_update_window,
@@ -472,10 +475,11 @@ def build_mgd_step(
 
     def step_fn_fused(params, state: MGDState, batch):
         n = state.step
-        c_tilde, c0, cost_metric = probe_once_fused(params, state, batch)
+        c_tilde, c0, cost_metric, aux = probe_once_fused(
+            params, state, batch)
         do_update = (n + 1) % cfg.tau_theta == 0
         metrics = {"cost": cost_metric, "c_tilde": c_tilde,
-                   "updated": do_update.astype(jnp.float32)}
+                   "updated": do_update.astype(jnp.float32), **aux}
         if cfg.replay:
             window = state.replay_c.shape[0]
             replay_c = state.replay_c.at[n % window].set(c_tilde)

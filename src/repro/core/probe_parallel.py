@@ -216,7 +216,7 @@ def build_probe_parallel_step(
                 step, pod_seed(cfg.seed, pod),
                 pert.ProbeCtx(signs=(1.0, -1.0), dtheta=cfg.dtheta,
                               tau_p=cfg.tau_p, impl=cfg.kernel_impl))
-            costs = plant.apply_perturbed(
+            costs, _ = plant.apply_perturbed(
                 params, batch, probe, step=step, tags=(2 * pod, 2 * pod + 1))
             c_plus, c_minus = costs[0], costs[1]
         else:

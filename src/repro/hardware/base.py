@@ -141,13 +141,16 @@ class Plant:
 
     def apply_perturbed(self, params: Pytree, batch, probe, *, step, tags):
         """Evaluate costs under θ ± θ̃ with θ̃ generated at the parameter
-        (Pallas kernels for in-process plants).  Returns a [len(tags)]
-        array of costs, one per sign in ``probe.ctx.signs``."""
+        (Pallas kernels for in-process plants).  Returns ``(costs, aux)``:
+        a [len(tags)] array of costs, one per sign in ``probe.ctx.signs``,
+        and a dict of what the model's probe_fn counts beside them (empty
+        unless the probe_fn returns ``(costs, aux)`` itself)."""
         if self.probe_fn is None:
             raise NotImplementedError(
                 f"{type(self).__name__} has no perturbed-apply interface "
                 "(construct it with probe_fn=... for the fused path)")
-        return self.probe_fn(params, batch, probe)
+        out = self.probe_fn(params, batch, probe)
+        return out if isinstance(out, tuple) else (out, {})
 
 
 class IdealPlant(Plant):

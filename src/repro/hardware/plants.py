@@ -117,13 +117,13 @@ class NoisyPlant(Plant):
         return jax.tree_util.tree_unflatten(treedef, out)
 
     def apply_perturbed(self, params, batch, probe, *, step, tags):
-        costs = super().apply_perturbed(params, batch, probe,
-                                        step=step, tags=tags)
+        costs, aux = super().apply_perturbed(params, batch, probe, step=step,
+                                             tags=tags)
         if self.cost_noise:
             noise = jnp.stack([_gauss_noise(self.seed, step, t)
                                for t in tags])
             costs = costs + self.cost_noise * noise
-        return costs
+        return costs, aux
 
 
 class QuantizedPlant(Plant):
@@ -230,12 +230,12 @@ class QuantizedPlant(Plant):
         if self.quantize_probes:
             raise NotImplementedError(
                 "quantize_probes=True has no fused kernel path")
-        costs = super().apply_perturbed(params, batch, probe,
-                                        step=step, tags=tags)
+        costs, aux = super().apply_perturbed(params, batch, probe, step=step,
+                                             tags=tags)
         if self.adc_bits is not None:
             costs = jnp.stack([self._adc(costs[i], step, t)
                                for i, t in enumerate(tags)])
-        return costs
+        return costs, aux
 
 
 class DriftingPlant(Plant):

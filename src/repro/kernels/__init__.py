@@ -5,6 +5,10 @@
 * ``perturbed_matmul_pair`` — the antithetic probe pair
   (x₊ @ (W+θ̃), x₋ @ (W−θ̃)) in one grid pass: W is read from HBM ONCE per
   central-difference probe pair.
+* ``perturbed_grouped_matmul`` — the same pair over a bank of experts
+  [E, K, N]: each stream's rows laid out by expert, ragged groups in
+  scalar prefetch, signs at each expert's offset in the bank
+  (``perturbed_grouped_matmul_pair``; the dropless MoE layer's products).
 * ``mgd_update``            — fused scalar-replay window update
   W −= (η/Δθ)·Σ_j C̃_j·θ̃_j, HBM traffic = one read + one write of W.
 * ``mgd_update_window``     — the same update in the optimizer's exact
@@ -15,8 +19,8 @@
 ``ref`` holds the pure-jnp oracles that share the exact counter hash.
 """
 from . import ops, ref
-from .ops import (mgd_update, mgd_update_window, perturbed_matmul,
-                  perturbed_matmul_pair)
+from .ops import (mgd_update, mgd_update_window, perturbed_grouped_matmul,
+                  perturbed_matmul, perturbed_matmul_pair)
 
 __all__ = ["ops", "ref", "perturbed_matmul", "perturbed_matmul_pair",
-           "mgd_update", "mgd_update_window"]
+           "perturbed_grouped_matmul", "mgd_update", "mgd_update_window"]

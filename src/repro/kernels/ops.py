@@ -23,6 +23,9 @@ import jax
 import jax.numpy as jnp
 
 from . import ref as _ref
+from .grouped_matmul import grouped_matmul_ref as _grouped_matmul_ref
+from .grouped_matmul import (
+    perturbed_grouped_matmul as _perturbed_grouped_matmul_pallas)
 from .mgd_update import mgd_update as _mgd_update_pallas
 from .mgd_update import mgd_update_window as _mgd_update_window_pallas
 from .perturbed_matmul import k_block
@@ -118,6 +121,26 @@ def perturbed_matmul_pair(xp, xm, w, lseed, *, dtheta, impl=None,
     )
     with _prep():
         return yp.reshape(*lead, n), ym.reshape(*lead, n)
+
+
+def perturbed_grouped_matmul(xs, w, lseed, start, nblk, last, *, signs,
+                             dtheta, bm, blocks, impl=None):
+    """Each stream's expert-laid-out rows [R, K] times its expert's
+    W[e] ± Δθ·signs_e (``grouped_matmul.perturbed_grouped_matmul``); the
+    streams of a central pair share one read of each W tile.  K must
+    have a block (``perturbed_matmul.k_block``).  ``lseed`` None
+    (``impl="ref"`` only) is the plain product."""
+    impl = impl or default_impl()
+    if impl == "ref":
+        return _grouped_matmul_ref(xs, w, lseed, start, nblk, signs=signs,
+                                   dtheta=dtheta, bm=bm)
+    pair = tuple(signs) == (1.0, -1.0)
+    return _perturbed_grouped_matmul_pallas(
+        tuple(xs), w, lseed, start, nblk, last, signs=tuple(signs),
+        dtheta=dtheta, bm=bm, blocks=blocks,
+        interpret=(impl == "interpret"),
+        name="perturbed_grouped_matmul_pair" if pair
+        else "perturbed_grouped_matmul")
 
 
 def _as_matrix(w):

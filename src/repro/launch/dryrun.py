@@ -47,8 +47,7 @@ def active_params(cfg, aparams) -> int:
     n = count_params(aparams)
     if cfg.n_experts:
         per_expert = 3 * cfg.d_model * cfg.d_ff
-        n_moe_layers = cfg.n_layers
-        n -= n_moe_layers * (cfg.n_experts - cfg.n_experts_active) * per_expert
+        n -= cfg.n_moe_layers * (cfg.n_held - cfg.n_experts_active) * per_expert
     return n
 
 

@@ -74,16 +74,18 @@ def chunked_causal_attention(
     q_block: int = 512,
     kv_block: int = 512,
     impl: str = "masked",
+    scale=None,
 ) -> jnp.ndarray:
     """Exact causal attention, O(S·block) memory.  Returns [B, S, H, Dv].
 
-    V's head dim may differ from Q/K's (MLA uses 192/128).
+    V's head dim may differ from Q/K's (MLA uses 192/128).  ``scale``
+    multiplies the scores (default 1/√D).
     """
     b, s, h, d = q.shape
     kvh = k.shape[2]
     dv = v.shape[-1]
     g = h // kvh
-    scale = 1.0 / np.sqrt(d)
+    scale = 1.0 / np.sqrt(d) if scale is None else scale
     q_block = min(q_block, s)
     kv_block = min(kv_block, s)
     if s % q_block or s % kv_block:
@@ -94,13 +96,15 @@ def chunked_causal_attention(
         padded = [jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
                   for x in (q, k, v)]
         out = chunked_causal_attention(
-            *padded, q_block=q_block, kv_block=kv_block, impl=impl)
+            *padded, q_block=q_block, kv_block=kv_block, impl=impl,
+            scale=scale)
         return out[:, :s]
     qg = q.reshape(b, s, kvh, g, d)
 
-    if impl == "balanced":
+    if impl == "balanced" and (s // q_block) % 2 == 0:
         return _balanced(qg, k, v, q_block, scale).reshape(b, s, h, dv)
-    assert impl == "masked", impl
+    # balanced pairs blocks; an odd count runs masked (the same values)
+    assert impl in ("masked", "balanced"), impl
     nq, nk = s // q_block, s // kv_block
 
     qs = qg.reshape(b, nq, q_block, kvh, g, d)

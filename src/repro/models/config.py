@@ -40,6 +40,28 @@ class ArchConfig:
     # so prefill-vs-decode parity is only exact with cf high enough to
     # never drop (tests use cf ≥ E/k).
     moe_capacity_factor: float = 1.25
+    # dropless expert layer (DeepSeek-V3): every routed token reaches its
+    # experts; the layer holds experts [expert_first, expert_first +
+    # experts_held) of n_experts (0: all) and computes their part only
+    moe_dropless: bool = False
+    expert_first: int = 0
+    experts_held: int = 0
+    # routing: softmax top-k, or DeepSeek-V3's sigmoid scores with
+    # group-limited top-k (n_groups groups, the topk_groups best kept)
+    router_score: str = "softmax"    # softmax | sigmoid
+    n_groups: int = 1
+    topk_groups: int = 1
+    routed_scale: float = 1.0
+    # leading dense layers before the MoE layers (first_k_dense_replace)
+    n_dense_layers: int = 0
+    d_ff_dense: int = 0             # their MLP width; 0 → d_ff
+    # YaRN rope scaling (DeepSeek-V3); factor 0 → plain rope
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 4096
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     # MLA (deepseek-v3)
     use_mla: bool = False
     q_lora_rank: int = 0
@@ -74,6 +96,15 @@ class ArchConfig:
     @property
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
+
+    @property
+    def n_held(self) -> int:
+        """Experts this layer holds (the chip's share)."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.n_dense_layers
 
     @property
     def jdtype(self):

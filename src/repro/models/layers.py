@@ -108,7 +108,9 @@ def glu_mlp(p, x):
 # subtree (``repro.core.utils.leaf_id_tree``) that anchors every leaf to the
 # global hash the host generator uses.  ``layer`` (traced, from a
 # stacked-layer scan) selects the row-major slice of stacked leaves via a
-# seed shift — see perturbations.shifted_leaf_seed.
+# seed shift — see perturbations.shifted_leaf_seed.  ``probe=None`` is the
+# plain forward: one stream, no perturbation — so a layer written once in
+# stream form is both the model's forward and its probe.
 
 
 def _stream_offset(layer, nelem):
@@ -122,6 +124,8 @@ def pleaf(leaf, leaf_id, probe, *, layer=None):
     """Per-stream perturbed values of a non-matmul leaf (or its layer
     slice): tuple of leaf + sign_i·θ̃, float order identical to the
     materializing optimizer path."""
+    if probe is None:
+        return (leaf,)
     offset = 0 if layer is None else _stream_offset(layer, leaf.size)
     theta = probe.leaf_theta(leaf.shape, leaf.dtype, leaf_id, offset=offset)
     return tuple(pert.apply_signed(leaf, theta, s) for s in probe.ctx.signs)
@@ -134,6 +138,8 @@ def pdense(p, xs, ids, probe, *, layer=None):
     falls back to a materialized θ̃.  ``ids`` is the leaf-id subtree aligned
     with ``p``; ``layer`` the stacked-bank slice index (or None).
     """
+    if probe is None:
+        return tuple(dense(p, x) for x in xs)
     ctx = probe.ctx
     w = p["w"]
     lseed = probe.lseed(ids["w"])
@@ -159,6 +165,16 @@ def prmsnorm(p, xs, ids, probe, *, layer=None, eps=1e-5):
     scales = pleaf(p["scale"], ids["scale"], probe, layer=layer)
     return tuple(rmsnorm({"scale": sc}, x, eps)
                  for sc, x in zip(scales, xs))
+
+
+@jax.named_scope("repro.model.mlp")
+def pglu_mlp(p, xs, ids, probe, layer=None):
+    """Perturbable SwiGLU MLP over the streams ``xs``."""
+    gs = pdense(p["gate"], xs, ids["gate"], probe, layer=layer)
+    us = pdense(p["up"], xs, ids["up"], probe, layer=layer)
+    hs = tuple(jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
+               for g, u, x in zip(gs, us, xs))
+    return pdense(p["down"], hs, ids["down"], probe, layer=layer)
 
 
 # --- convolutions for the paper-scale CNNs ---------------------------------

@@ -18,11 +18,12 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
+from repro.core.utils import leaf_id_tree
 from .attention import chunked_causal_attention, NEG_INF
-from .layers import dense, dense_init, rmsnorm, rmsnorm_init
-from .rope import apply_rope
+from .layers import dense_init, pdense, prmsnorm, rmsnorm_init
+from .rope import apply_rope, cfg_rope_freqs, cfg_rope_mscale, \
+    yarn_softmax_scale
 
 
 def mla_init(key, cfg, dtype):
@@ -50,50 +51,81 @@ def mla_init(key, cfg, dtype):
     return p
 
 
-def _queries(p, x, cfg):
-    b, s, _ = x.shape
-    h = cfg.n_heads
+def mla_rope(x, positions, cfg):
+    """DeepSeek-V3's rope on the decoupled rope dims: interleaved pairs,
+    YaRN frequencies and cos/sin scale where the config sets them."""
+    return apply_rope(x, positions, cfg.rope_theta,
+                      inv_freq=cfg_rope_freqs(cfg, x.shape[-1]),
+                      mscale=cfg_rope_mscale(cfg), interleaved=True)
+
+
+def softmax_scale(cfg) -> float:
+    return yarn_softmax_scale(
+        cfg, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+
+
+def _queries(p, xs, cfg, ids, probe, layer):
+    """Per-stream (q_nope [B,S,H,dn], q_rope [B,S,H,dr]), unrotated."""
+    b, s, _ = xs[0].shape
     qd = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
     if cfg.q_lora_rank:
-        q = dense(p["wq_b"], rmsnorm(p["q_norm"], dense(p["wq_a"], x)))
+        qa = pdense(p["wq_a"], xs, ids["wq_a"], probe, layer=layer)
+        qa = prmsnorm(p["q_norm"], qa, ids["q_norm"], probe, layer=layer,
+                      eps=cfg.norm_eps)
+        qs = pdense(p["wq_b"], qa, ids["wq_b"], probe, layer=layer)
     else:
-        q = dense(p["wq"], x)
-    q = q.reshape(b, s, h, qd)
-    return jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)  # nope, rope
+        qs = pdense(p["wq"], xs, ids["wq"], probe, layer=layer)
+    return tuple(jnp.split(q.reshape(b, s, cfg.n_heads, qd),
+                           [cfg.qk_nope_head_dim], axis=-1) for q in qs)
 
 
-def _kv_latent(p, x, cfg, positions):
-    """Returns (c_kv [B,S,r] normalized, k_rope [B,S,1,dr] rotated)."""
-    ckv_full = dense(p["wkv_a"], x)
-    c_kv, k_rope = jnp.split(ckv_full, [cfg.kv_lora_rank], axis=-1)
-    c_kv = rmsnorm(p["kv_norm"], c_kv)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)
-    return c_kv, k_rope
+def _kv_latent(p, xs, cfg, positions, ids, probe, layer):
+    """Per-stream (c_kv [B,S,r] normalized, k_rope [B,S,1,dr] rotated)."""
+    ckvs = pdense(p["wkv_a"], xs, ids["wkv_a"], probe, layer=layer)
+    cs, krs = zip(*(jnp.split(c, [cfg.kv_lora_rank], axis=-1) for c in ckvs))
+    cs = prmsnorm(p["kv_norm"], cs, ids["kv_norm"], probe, layer=layer,
+                  eps=cfg.norm_eps)
+    krs = tuple(mla_rope(kr[:, :, None, :], positions, cfg) for kr in krs)
+    return cs, krs
+
+
+def mla_streams(p, xs, positions, cfg, ids, probe, layer=None, *,
+                q_block=512, kv_block=512, impl="masked"):
+    """Expand-form causal MLA over full sequences, one result per stream
+    (``layers.pdense``'s stream convention; ``probe=None`` is the plain
+    forward).  Returns (ys [B,S,d_model] per stream, caches (c_kv, k_rope)
+    per stream)."""
+    b, s, _ = xs[0].shape
+    h = cfg.n_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    qs = _queries(p, xs, cfg, ids, probe, layer)
+    cs, krs = _kv_latent(p, xs, cfg, positions, ids, probe, layer)
+    kvs = pdense(p["wkv_b"], cs, ids["wkv_b"], probe, layer=layer)
+    outs = []
+    for (q_nope, q_rope), kv, k_rope in zip(qs, kvs, krs):
+        k_nope, v = jnp.split(kv.reshape(b, s, h, dn + dv), [dn], axis=-1)
+        q = jnp.concatenate([q_nope, mla_rope(q_rope, positions, cfg)], -1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope, (b, s, h, dr))], axis=-1)
+        att = chunked_causal_attention(
+            q, k, v, q_block=q_block, kv_block=kv_block, impl=impl,
+            scale=softmax_scale(cfg))
+        outs.append(att.reshape(b, s, h * dv))
+    ys = pdense(p["wo"], tuple(outs), ids["wo"], probe, layer=layer)
+    return ys, tuple((c, kr[:, :, 0, :]) for c, kr in zip(cs, krs))
 
 
 def mla_attention(p, x, positions, cfg, *, q_block=512, kv_block=512,
                   impl="masked"):
-    """Expand-form causal MLA over a full sequence.
+    """Expand-form causal MLA over a full sequence: the one-stream, plain
+    case of ``mla_streams``.
 
     Returns (y [B,S,d_model], cache = (c_kv, k_rope squeezed)).
     """
-    b, s, _ = x.shape
-    h = cfg.n_heads
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-
-    q_nope, q_rope = _queries(p, x, cfg)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
-    c_kv, k_rope = _kv_latent(p, x, cfg, positions)
-
-    kv = dense(p["wkv_b"], c_kv).reshape(b, s, h, dn + dv)
-    k_nope, v = jnp.split(kv, [dn], axis=-1)
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k = jnp.concatenate(
-        [k_nope, jnp.broadcast_to(k_rope, (b, s, h, dr))], axis=-1)
-    attn = chunked_causal_attention(
-        q, k, v, q_block=q_block, kv_block=kv_block, impl=impl)
-    y = dense(p["wo"], attn.reshape(b, s, h * dv))
-    return y, (c_kv, k_rope[:, :, 0, :])
+    (y,), (cache,) = mla_streams(p, (x,), positions, cfg, leaf_id_tree(p),
+                                 None, q_block=q_block, kv_block=kv_block,
+                                 impl=impl)
+    return y, cache
 
 
 def _absorb_weights(p, cfg):
@@ -111,13 +143,12 @@ def mla_decode(p, x1, cache, length, cfg):
     already containing this token's entries at position length−1.
     """
     b = x1.shape[0]
-    h = cfg.n_heads
-    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     c_cache, r_cache = cache
     pos = jnp.full((b, 1), length - 1, jnp.int32)
 
-    q_nope, q_rope = _queries(p, x1, cfg)
-    q_rope = apply_rope(q_rope, pos, cfg.rope_theta)        # [B,1,H,dr]
+    ids = leaf_id_tree(p)
+    ((q_nope, q_rope),) = _queries(p, (x1,), cfg, ids, None, None)
+    q_rope = mla_rope(q_rope, pos, cfg)                     # [B,1,H,dr]
     w_uk, w_uv = _absorb_weights(p, cfg)
 
     # fold W_UK into the query: q_eff [B,H,r]
@@ -129,13 +160,14 @@ def mla_decode(p, x1, cache, length, cfg):
                    c_cache.astype(jnp.float32))
         + jnp.einsum("bhd,bsd->bhs", q_rope[:, 0].astype(jnp.float32),
                      r_cache.astype(jnp.float32))
-    ) / np.sqrt(dn + dr)
+    ) * softmax_scale(cfg)
     idx = jnp.arange(c_cache.shape[1])
     scores = jnp.where(idx[None, None, :] < length, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     ctx = jnp.einsum("bhs,bsr->bhr", probs, c_cache.astype(jnp.float32))
     attn = jnp.einsum("bhr,rhv->bhv", ctx, w_uv.astype(jnp.float32))
-    y = dense(p["wo"], attn.reshape(b, 1, -1).astype(x1.dtype))
+    (y,) = pdense(p["wo"], (attn.reshape(b, 1, -1).astype(x1.dtype),),
+                  ids["wo"], None)
     return y
 
 
@@ -143,7 +175,8 @@ def mla_cache_update(p, x1, cache, length, cfg):
     """Compute this token's (c_kv, k_rope) and write them at length−1."""
     b = x1.shape[0]
     pos = jnp.full((b, 1), length - 1, jnp.int32)
-    c_kv, k_rope = _kv_latent(p, x1, cfg, pos)
+    (c_kv,), (k_rope,) = _kv_latent(p, (x1,), cfg, pos, leaf_id_tree(p),
+                                    None, None)
     c_cache, r_cache = cache
     c_cache = jax.lax.dynamic_update_slice_in_dim(
         c_cache, c_kv.astype(c_cache.dtype), length - 1, 1)

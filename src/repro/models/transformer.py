@@ -28,12 +28,13 @@ from repro.distributed.sharding import shard
 from .attention import chunked_causal_attention, decode_attention
 from .config import ArchConfig
 from .layers import (dense, dense_init, embed, embedding_init, glu_mlp,
-                     glu_mlp_init, pdense, pleaf, prmsnorm, rmsnorm,
+                     glu_mlp_init, pdense, pglu_mlp, pleaf, prmsnorm, rmsnorm,
                      rmsnorm_init)
 from .mamba2 import (mamba2_block, mamba2_block_init, mamba2_block_step,
                      mamba2_state_init)
-from .mla import (mla_attention, mla_cache_update, mla_decode, mla_init)
-from .moe import moe_apply, moe_init
+from .mla import (mla_attention, mla_cache_update, mla_decode, mla_init,
+                  mla_streams)
+from .moe import moe_apply, moe_init, moe_streams
 from .rope import apply_mrope, apply_rope
 from .rwkv6 import (rwkv6_block, rwkv6_block_init, rwkv6_block_step,
                     rwkv6_state_init)
@@ -110,7 +111,9 @@ def attn_decode_step(p, x1, positions, kcache, vcache, length, cfg):
 # ---------------------------------------------------------------------------
 
 
-def block_init(key, cfg: ArchConfig, dtype):
+def block_init(key, cfg: ArchConfig, dtype, dense: bool = False):
+    """One layer's parameters; ``dense`` marks a leading dense layer of
+    an MoE model (its MLP is ``d_ff_dense`` wide)."""
     ka, km = jax.random.split(key)
     p = {"ln1": rmsnorm_init(cfg.d_model, dtype),
          "ln2": rmsnorm_init(cfg.d_model, dtype)}
@@ -118,20 +121,24 @@ def block_init(key, cfg: ArchConfig, dtype):
         p["attn"] = mla_init(ka, cfg, dtype)
     else:
         p["attn"] = attn_init(ka, cfg, dtype)
-    if cfg.n_experts:
+    if cfg.n_experts and not dense:
         p["moe"] = moe_init(km, cfg, dtype)
     else:
-        p["mlp"] = glu_mlp_init(km, cfg.d_model, cfg.d_ff, dtype)
+        p["mlp"] = glu_mlp_init(
+            km, cfg.d_model, (cfg.d_ff_dense or cfg.d_ff) if dense
+            else cfg.d_ff, dtype)
     return p
 
 
 def _mlp_part(p, x, cfg):
-    if cfg.n_experts:
-        y = moe_apply(p["moe"], x, cfg, group_size=cfg.moe_group_size,
-                      capacity_factor=cfg.moe_capacity_factor)
-    else:
-        y = glu_mlp(p["mlp"], x)
-    return y
+    if "mlp" in p:
+        return glu_mlp(p["mlp"], x)
+    if cfg.moe_dropless:
+        (y,), _ = moe_streams(p["moe"], (x,), cfg, leaf_id_tree(p["moe"]),
+                              None)
+        return y
+    return moe_apply(p["moe"], x, cfg, group_size=cfg.moe_group_size,
+                     capacity_factor=cfg.moe_capacity_factor)
 
 
 def block_apply(p, x, positions, cfg: ArchConfig):
@@ -232,6 +239,11 @@ def model_init(cfg: ArchConfig, key):
     dtype = cfg.jdtype
     k_emb, k_layers, k_shared = jax.random.split(key, 3)
     params: Dict[str, Any] = {"embed": _embed_init(k_emb, cfg, dtype)}
+    if cfg.n_dense_layers:
+        init_dense = functools.partial(block_init, cfg=cfg, dtype=dtype,
+                                       dense=True)
+        params["dense_layers"] = jax.vmap(init_dense)(
+            _layer_keys(k_shared, cfg.n_dense_layers))
     if cfg.family == "ssm":
         init_one = functools.partial(rwkv6_block_init, cfg=cfg, dtype=dtype)
         params["layers"] = jax.vmap(init_one)(_layer_keys(k_layers, cfg.n_layers))
@@ -242,8 +254,34 @@ def model_init(cfg: ArchConfig, key):
         params["shared_attn"] = block_init(k_shared, cfg, dtype)
     else:
         init_one = functools.partial(block_init, cfg=cfg, dtype=dtype)
-        params["layers"] = jax.vmap(init_one)(_layer_keys(k_layers, cfg.n_layers))
+        params["layers"] = jax.vmap(init_one)(
+            _layer_keys(k_layers, cfg.n_moe_layers))
     return params
+
+
+def _stack_keys(params):
+    """Keys of the decoder's layer stacks in order: leading dense layers,
+    then the rest."""
+    return [k for k in ("dense_layers", "layers") if k in params]
+
+
+def _stacks(params):
+    return [params[k] for k in _stack_keys(params)]
+
+
+def _split_layers(tree, params):
+    """Split a tree stacked over all layers as ``_stacks`` orders them."""
+    n = [jax.tree_util.tree_leaves(st)[0].shape[0] for st in _stacks(params)]
+    if len(n) == 1:
+        return [tree]
+    return [jax.tree_util.tree_map(lambda a: a[:n[0]], tree),
+            jax.tree_util.tree_map(lambda a: a[n[0]:], tree)]
+
+
+def _concat_layers(trees):
+    if len(trees) == 1:
+        return trees[0]
+    return jax.tree_util.tree_map(lambda *a: jnp.concatenate(a), *trees)
 
 
 def _hybrid_plan(cfg: ArchConfig):
@@ -314,8 +352,11 @@ def model_forward(params, cfg: ArchConfig, batch, *, return_state=False,
             x, kv = block_apply(lp, x, positions, cfg)
             return x, kv
 
-        x, kvs = jax.lax.scan(body, x, params["layers"])
-        new_state = kvs
+        kvs = []
+        for stack in _stacks(params):
+            x, kv = jax.lax.scan(body, x, stack)
+            kvs.append(kv)
+        new_state = _concat_layers(kvs)
 
     x = rmsnorm(params["embed"]["ln_f"], x, cfg.norm_eps)
     logits = _logits(params["embed"], cfg, x)
@@ -344,10 +385,12 @@ def model_loss(params, cfg: ArchConfig, batch):
 # Fused probe path (MGD): forward under θ ± θ̃ without materializing θ̃
 # ---------------------------------------------------------------------------
 #
-# The GQA/MLP weight matmuls — the HBM-dominant leaves — route through the
-# Pallas perturbed-matmul kernels (sign generation in VMEM; the antithetic
-# central pair reads each W tile ONCE).  Norm scales, biases and the
-# embedding table fall back to materialized θ̃ (O(d) or gather-bound).
+# The weight matmuls — GQA or MLA projections, MLPs, the head: the
+# HBM-dominant leaves — route through the Pallas perturbed-matmul kernels
+# (sign generation in VMEM; the antithetic central pair reads each W tile
+# ONCE), and the dropless expert layer's held banks through the grouped
+# kernel (``moe.moe_streams``).  Norm scales, biases, the f32 router and
+# the embedding table fall back to materialized θ̃ (O(d) or gather-bound).
 # Stacked-layer banks are addressed through the per-layer seed shift, so the
 # in-kernel sign pattern is bit-identical to the host generator's view of
 # the stacked leaf.
@@ -388,38 +431,42 @@ def _pattn_apply(p, xs, positions, cfg: ArchConfig, ids, probe, layer):
     return pdense(p["wo"], tuple(ys), ids["wo"], probe, layer=layer)
 
 
-@jax.named_scope("repro.model.mlp")
-def _pglu_mlp(p, xs, ids, probe, layer):
-    gs = pdense(p["gate"], xs, ids["gate"], probe, layer=layer)
-    us = pdense(p["up"], xs, ids["up"], probe, layer=layer)
-    hs = tuple(jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
-               for g, u, x in zip(gs, us, xs))
-    return pdense(p["down"], hs, ids["down"], probe, layer=layer)
+@jax.named_scope("repro.model.attn")
+def _pmla_apply(p, xs, positions, cfg: ArchConfig, ids, probe, layer):
+    ys, _ = mla_streams(p, xs, positions, cfg, ids, probe, layer,
+                        q_block=cfg.attn_q_block, kv_block=cfg.attn_kv_block,
+                        impl=cfg.attn_impl)
+    return ys
 
 
 @jax.named_scope("repro.model.block")
 def _pblock_apply(p, xs, positions, cfg: ArchConfig, ids, probe, layer):
+    """One layer over the probe streams → (streams, rows each stream
+    routed to each held expert, or None without experts)."""
     seq_ax = "sp" if cfg.seq_parallel else None
     xn = prmsnorm(p["ln1"], xs, ids["ln1"], probe, layer=layer,
                   eps=cfg.norm_eps)
-    att = _pattn_apply(p["attn"], xn, positions, cfg, ids["attn"], probe,
-                       layer)
+    attn = _pmla_apply if cfg.use_mla else _pattn_apply
+    att = attn(p["attn"], xn, positions, cfg, ids["attn"], probe, layer)
     xs = tuple(x + a for x, a in zip(xs, att))
     xs = tuple(shard(x, "batch", seq_ax, None) for x in xs)
-    ys = _pglu_mlp(
-        p["mlp"],
-        prmsnorm(p["ln2"], xs, ids["ln2"], probe, layer=layer,
-                 eps=cfg.norm_eps),
-        ids["mlp"], probe, layer)
+    xn = prmsnorm(p["ln2"], xs, ids["ln2"], probe, layer=layer,
+                  eps=cfg.norm_eps)
+    rows = None
+    if "mlp" in p:
+        ys = pglu_mlp(p["mlp"], xn, ids["mlp"], probe, layer)
+    else:
+        ys, rows = moe_streams(p["moe"], xn, cfg, ids["moe"], probe, layer)
     xs = tuple(x + y for x, y in zip(xs, ys))
-    return tuple(shard(x, "batch", seq_ax, None) for x in xs)
+    return tuple(shard(x, "batch", seq_ax, None) for x in xs), rows
 
 
 def supports_fused_probe(cfg: ArchConfig) -> bool:
-    """Dense GQA decoders (incl. vlm/audio stub frontends) have the fully
-    fused probe path; MoE/MLA/SSM/hybrid fall back to materializing."""
-    return (cfg.family in ("dense", "vlm", "audio")
-            and not cfg.use_mla and not cfg.n_experts)
+    """Dense GQA decoders (incl. vlm/audio stub frontends), MLA, and the
+    dropless expert layer have the fully fused probe path; capacity MoE,
+    SSM and hybrid fall back to materializing."""
+    return (cfg.family in ("dense", "vlm", "audio", "moe")
+            and (not cfg.n_experts or cfg.moe_dropless))
 
 
 def model_forward_perturbed(params, cfg: ArchConfig, batch, probe):
@@ -427,6 +474,13 @@ def model_forward_perturbed(params, cfg: ArchConfig, batch, probe):
 
     Returns a tuple of logits arrays, one per ``probe.ctx.signs`` entry.
     """
+    return _forward_perturbed(params, cfg, batch, probe)[0]
+
+
+def _forward_perturbed(params, cfg: ArchConfig, batch, probe):
+    """(per-sign logits, aux): aux holds ``moe_rows`` [MoE layers,
+    signs, held experts], the rows each stream routed to each held
+    expert, for a model with the dropless expert layer."""
     assert supports_fused_probe(cfg), cfg.family
     ids = leaf_id_tree(params)
     emb, eids = params["embed"], ids["embed"]
@@ -448,15 +502,18 @@ def model_forward_perturbed(params, cfg: ArchConfig, batch, probe):
     b, s, _ = xs[0].shape
     positions = _positions(cfg, batch, s, b)
 
-    def body(carry, layer_in):
-        lp, l = layer_in
-        out = _pblock_apply(lp, carry, positions, cfg, ids["layers"], probe,
-                            l)
-        return out, None
-
+    aux = {}
     with jax.named_scope("repro.model.layers"):
-        xs, _ = jax.lax.scan(
-            body, xs, (params["layers"], jnp.arange(cfg.n_layers)))
+        for key in _stack_keys(params):
+            def body(carry, layer_in, key=key):
+                lp, l = layer_in
+                return _pblock_apply(lp, carry, positions, cfg, ids[key],
+                                     probe, l)
+
+            n = jax.tree_util.tree_leaves(params[key])[0].shape[0]
+            xs, rows = jax.lax.scan(body, xs, (params[key], jnp.arange(n)))
+            if rows is not None:
+                aux["moe_rows"] = rows
     xs = prmsnorm(emb["ln_f"], xs, eids["ln_f"], probe, eps=cfg.norm_eps)
     if cfg.tie_embeddings:
         logits = tuple(x @ t.T for x, t in zip(xs, tables))
@@ -466,20 +523,24 @@ def model_forward_perturbed(params, cfg: ArchConfig, batch, probe):
     if cfg.n_codebooks:
         logits = tuple(l.reshape(b, s, cfg.n_codebooks, cfg.vocab)
                        for l in logits)
-    return logits
+    return logits, aux
 
 
 def model_probe_costs(params, cfg: ArchConfig, batch, probe):
-    """probe_fn for ``MGDConfig(fused=True)``: [n_signs] xent costs.
+    """probe_fn for ``MGDConfig(fused=True)``: [n_signs] xent costs, and
+    beside them, for a model with the dropless expert layer, the counter
+    ``{"moe_rows": …}`` (``_forward_perturbed``) as ``(costs, aux)``.
 
-    Fused for dense GQA decoders; other families materialize θ̃ per sign
-    with the exact float order of the unfused optimizer path.
+    Fused for the families ``supports_fused_probe`` names; others
+    materialize θ̃ per sign with the exact float order of the unfused
+    optimizer path.
     """
     if supports_fused_probe(cfg):
-        logits = model_forward_perturbed(params, cfg, batch, probe)
+        logits, aux = _forward_perturbed(params, cfg, batch, probe)
         with jax.named_scope("repro.model.loss"):
-            return jnp.stack(
+            costs = jnp.stack(
                 [_loss_from_logits(l, batch["labels"]) for l in logits])
+        return (costs, aux) if aux else costs
     theta = pert.generate(
         params, ptype="rademacher", step=probe.step, seed=probe.seed,
         dtheta=probe.ctx.dtheta, tau_p=probe.ctx.tau_p)
@@ -622,24 +683,22 @@ def model_decode(params, cfg: ArchConfig, tokens, cache, embeds=None):
                 lambda a: a.reshape(n_mamba, *a.shape[2:]), new_m),
             "k": kc, "v": vc, "length": length,
         }
-    elif cfg.use_mla:
-        def body(x, layer):
-            lp, cc, rr = layer
-            x, (cc, rr) = block_decode(lp, x, pos, (cc, rr), length, cfg)
-            return x, (cc, rr)
-
-        x1, (c, r) = jax.lax.scan(
-            body, x1, (params["layers"], cache["c_kv"], cache["k_rope"]))
-        new_cache = {"c_kv": c, "k_rope": r, "length": length}
     else:
-        def body(x, layer):
-            lp, kc, vc = layer
-            x, (kc, vc) = block_decode(lp, x, pos, (kc, vc), length, cfg)
-            return x, (kc, vc)
+        names = ("c_kv", "k_rope") if cfg.use_mla else ("k", "v")
 
-        x1, (kc, vc) = jax.lax.scan(
-            body, x1, (params["layers"], cache["k"], cache["v"]))
-        new_cache = {"k": kc, "v": vc, "length": length}
+        def body(x, layer):
+            lp, c0, c1 = layer
+            x, (c0, c1) = block_decode(lp, x, pos, (c0, c1), length, cfg)
+            return x, (c0, c1)
+
+        outs = []
+        for stack, (c0, c1) in zip(
+                _stacks(params),
+                _split_layers((cache[names[0]], cache[names[1]]), params)):
+            x1, out = jax.lax.scan(body, x1, (stack, c0, c1))
+            outs.append(out)
+        c0, c1 = _concat_layers(outs)
+        new_cache = {names[0]: c0, names[1]: c1, "length": length}
 
     x1 = rmsnorm(params["embed"]["ln_f"], x1, cfg.norm_eps)
     logits = _logits(params["embed"], cfg, x1)[:, 0]

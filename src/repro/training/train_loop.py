@@ -25,6 +25,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro.api.driver import (MGDDriver, driver as build_driver, state_step,
@@ -268,12 +269,21 @@ def train_mgd(
                 return p, s, m
             return run
     else:
+        # A program of several steps donates the weights and state it is
+        # handed: its loop carries them in those buffers, so it holds one
+        # set of weights, not two.  A program of one step does not: its
+        # update writes fresh buffers, and donated inputs would cost a copy
+        # of every updated leaf back into them.  The loop then trains
+        # copies of its own, so the caller's arrays are never donated.
+        own = jax.jit(lambda tree: jax.tree_util.tree_map(jnp.copy, tree))
+        if chunk > 1:
+            params, state = own((params, state))
+
         def make_runner(n):
-            @jax.jit
             def run(p, s):
                 (p, s), ms = jax.lax.scan(body, (p, s), None, length=n)
                 return p, s, jax.tree_util.tree_map(lambda x: x[-1], ms)
-            return run
+            return jax.jit(run, donate_argnums=(0, 1) if n > 1 else ())
 
     # double-buffered farms (ChipFarm(pipeline=True)) leave parameter
     # writes in flight between steps; state-dependent boundaries —
@@ -288,7 +298,10 @@ def train_mgd(
 
     # Host spans on the profiler's clock, recorded only while a profiler
     # session is open: each device program's dispatch and the host read
-    # of its metrics.
+    # of its metrics.  A model with the dropless expert layer hands back
+    # ``moe_rows`` (rows each probe stream routed to each held expert, per
+    # layer) with the metrics; the same read takes it, and the span
+    # ``repro.moe.rows`` carries its values as stats.
     runners = {}
     history = []
     done = start_step
@@ -303,19 +316,31 @@ def train_mgd(
         with TraceAnnotation("repro.loop.dispatch"):
             params, state, metrics = runners[n](params, state)
         with TraceAnnotation("repro.loop.read"):
+            rows = metrics.pop("moe_rows", None)
             rec = {k: float(v) for k, v in metrics.items()}
+            if rows is not None:
+                rows = np.asarray(rows)
+        if rows is not None:
+            rec["moe_rows"] = rows.tolist()
+            with TraceAnnotation("repro.moe.rows", shape="x".join(
+                    map(str, rows.shape)), values=" ".join(
+                    map(str, rows.reshape(-1).tolist()))):
+                pass
         done += n
         if eval_fn and eval_every and (done % eval_every < chunk):
             fence()
             rec.update({k: float(v) for k, v in eval_fn(params).items()})
         history.append((done, rec))
         if log:
-            msg = " ".join(f"{k}={v:.4g}" for k, v in rec.items())
+            msg = " ".join(f"{k}={v:.4g}" for k, v in rec.items()
+                           if isinstance(v, float))
             log(f"[mgd] step {done}/{num_steps} {msg} "
                 f"({(time.time()-t0):.1f}s)")
         if recal_every and done % recal_every == 0 and done < num_steps:
             fence()
             params = _recalibrate(drv, params, shadow, done)
+            if not external and chunk > 1:
+                params = own(params)    # the shadow itself, with no plant
             if log:
                 log(f"[mgd] step {done}: scheduled recalibration "
                     f"(full rewrite from shadow params)")

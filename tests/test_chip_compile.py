@@ -170,3 +170,149 @@ def test_fused_step_names_its_kernels_and_program_scopes(one_chip):
     scopes = set(re.findall(r"repro\.[a-z]+\.[a-z]+",
                             " ".join(re.findall(r'op_name="([^"]*)"', text))))
     assert scopes == set(PROGRAM_SCOPES)
+
+
+# --- DeepSeek-V3 widths, and the dense path's calls held as they were -----
+
+DSV3_PAIR_SHAPES = [(7168, 1536), (1536, 24576), (7168, 576), (512, 32768),
+                    (16384, 7168), (7168, 18432), (18432, 7168),
+                    (7168, 16160)]
+
+
+@pytest.mark.parametrize("k,n", DSV3_PAIR_SHAPES,
+                         ids=[f"{k}x{n}" for k, n in DSV3_PAIR_SHAPES])
+def test_pair_kernel_compiles_at_deepseek_v3_widths(one_chip, k, n):
+    """The pair kernel at each DeepSeek-V3 weight it runs (MLA's five
+    projections, the dense MLP, the head), 8192 rows."""
+    def s(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    _compile(lambda xp, xm, w, l: ops.perturbed_matmul_pair(
+        xp, xm, w, l, dtheta=2 ** -6, impl="pallas"),
+        s((8192, k), BF16), s((8192, k), BF16), s((k, n), BF16),
+        s((), jnp.uint32))
+
+
+@pytest.mark.parametrize("k,n", [(7168, 2048), (2048, 7168)],
+                         ids=["gate_up", "down"])
+def test_grouped_kernel_compiles_at_deepseek_v3_widths(one_chip, k, n):
+    """The grouped probe-pair kernel over 8 held experts at 8192 tokens:
+    the dropless layer's pass buffers and blocks (``moe.share_sizes``)."""
+    from repro.configs import get_config
+    from repro.models.moe import share_sizes
+
+    cfg = get_config("deepseek-v3-671b").replace(experts_held=8)
+    bm, _, blocks_total, blocks = share_sizes(cfg, 8192)
+
+    def s(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    meta = s((2, 8), jnp.int32)
+    text = _compile(lambda xp, xm, w, l, a, b, c: ops.perturbed_grouped_matmul(
+        (xp, xm), w, l, a, b, c, signs=(1.0, -1.0), dtheta=2 ** -6, bm=bm,
+        blocks=blocks, impl="pallas"),
+        s((bm * blocks_total, k), BF16), s((bm * blocks_total, k), BF16),
+        s((8, k, n), BF16), s((), jnp.uint32), meta, meta, meta).as_text()
+    assert "perturbed_grouped_matmul_pair" in text
+
+
+def _pallas_calls(jaxpr, out):
+    """(name, grid, block shapes) of every pallas_call in a jaxpr."""
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            gm = e.params["grid_mapping"]
+            out.append((str(e.params["name"]), tuple(gm.grid), tuple(
+                tuple(b.block_size for b in m.block_shape)
+                for m in gm.block_mappings)))
+        for v in e.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _pallas_calls(inner, out)
+    return out
+
+
+_PAIR_BLOCKS = ((2048, 512), (2048, 512), (512, 1024), (2048, 1024),
+                (2048, 1024))
+_UPD = ((256, 256), (256, 256))
+# the dense fused step's Pallas calls at 2 layers, batch 4×512, as the
+# dense path picks them: (name, grid, blocks) → count.  A change made for
+# another architecture must leave them as they are.
+DENSE_CALLS = {
+    "qwen3-14b": {
+        ("mgd_update_window", (1, 1), ((2, 128), (2, 128))): 2,
+        ("mgd_update_window", (1, 20), ((2, 256), (2, 256))): 2,
+        ("mgd_update_window", (20, 594), _UPD): 1,
+        ("mgd_update_window", (40, 4), _UPD): 2,
+        ("mgd_update_window", (40, 20), _UPD): 2,
+        ("mgd_update_window", (40, 68), _UPD): 2,
+        ("mgd_update_window", (136, 20), _UPD): 1,
+        ("mgd_update_window", (594, 20), _UPD): 1,
+        ("perturbed_matmul_pair", (1, 1, 10), _PAIR_BLOCKS): 2,
+        ("perturbed_matmul_pair", (1, 5, 10), _PAIR_BLOCKS): 2,
+        ("perturbed_matmul_pair", (1, 5, 34), _PAIR_BLOCKS): 1,
+        ("perturbed_matmul_pair", (1, 17, 10), _PAIR_BLOCKS): 2,
+        ("perturbed_matmul_pair", (1, 149, 10), _PAIR_BLOCKS): 1},
+    "mistral-nemo-12b": {
+        ("mgd_update_window", (1, 20), ((2, 256), (2, 256))): 2,
+        ("mgd_update_window", (20, 512), _UPD): 1,
+        ("mgd_update_window", (32, 20), _UPD): 1,
+        ("mgd_update_window", (40, 4), _UPD): 2,
+        ("mgd_update_window", (40, 16), _UPD): 1,
+        ("mgd_update_window", (40, 56), _UPD): 2,
+        ("mgd_update_window", (112, 20), _UPD): 1,
+        ("mgd_update_window", (512, 20), _UPD): 1,
+        ("perturbed_matmul_pair", (1, 1, 10), _PAIR_BLOCKS): 2,
+        ("perturbed_matmul_pair", (1, 4, 10), _PAIR_BLOCKS): 1,
+        ("perturbed_matmul_pair", (1, 5, 8), _PAIR_BLOCKS): 1,
+        ("perturbed_matmul_pair", (1, 5, 28), _PAIR_BLOCKS): 1,
+        ("perturbed_matmul_pair", (1, 14, 10), _PAIR_BLOCKS): 2,
+        ("perturbed_matmul_pair", (1, 128, 10), _PAIR_BLOCKS): 1},
+}
+DENSE_LEAVES = {
+    "qwen3-14b": [
+        "embed/head/w", "embed/ln_f/scale", "embed/tok/table",
+        "layers/attn/k_norm/scale", "layers/attn/q_norm/scale",
+        "layers/attn/wk/w", "layers/attn/wo/w", "layers/attn/wq/w",
+        "layers/attn/wv/w", "layers/ln1/scale", "layers/ln2/scale",
+        "layers/mlp/down/w", "layers/mlp/gate/w", "layers/mlp/up/w"],
+    "mistral-nemo-12b": [
+        "embed/head/w", "embed/ln_f/scale", "embed/tok/table",
+        "layers/attn/wk/w", "layers/attn/wo/w", "layers/attn/wq/w",
+        "layers/attn/wv/w", "layers/ln1/scale", "layers/ln2/scale",
+        "layers/mlp/down/w", "layers/mlp/gate/w", "layers/mlp/up/w"],
+}
+
+
+@pytest.mark.parametrize("arch", sorted(DENSE_CALLS))
+def test_dense_step_keeps_its_pallas_calls_and_tiles(arch):
+    """The benchmarked dense configs' fused step traces to the Pallas
+    calls, grids and blocks written in ``DENSE_CALLS``."""
+    from collections import Counter
+
+    cfg = get_config(arch).replace(n_layers=2)
+    shapes = jax.eval_shape(lambda: model_init(cfg, jax.random.PRNGKey(0)))
+    drv = repro.driver(
+        "discrete",
+        repro.DriverConfig(fused=True, mode="central", kernel_impl="pallas",
+                           dtheta=2 ** -6, eta=2e-4),
+        lambda p, b: model_loss(p, cfg, b),
+        probe_fn=make_transformer_probe_fn(cfg))
+    tokens = jax.ShapeDtypeStruct((4, 512), jnp.int32)
+    jaxpr = jax.make_jaxpr(drv.step)(shapes, jax.eval_shape(drv.init, shapes),
+                                     {"tokens": tokens, "labels": tokens})
+    assert Counter(_pallas_calls(jaxpr.jaxpr, [])) == DENSE_CALLS[arch]
+
+
+@pytest.mark.parametrize("arch", sorted(DENSE_LEAVES))
+def test_dense_leaf_ids_unchanged(arch):
+    """Leaf ids (the perturbation hash's) of the dense configs: the paths
+    of ``DENSE_LEAVES`` in that order (a stack added for other models
+    must not shift them)."""
+    from repro.core.utils import leaf_id_tree
+
+    shapes = jax.eval_shape(lambda: model_init(
+        get_config(arch).replace(n_layers=2), jax.random.PRNGKey(0)))
+    ids = jax.tree_util.tree_flatten_with_path(leaf_id_tree(shapes))[0]
+    assert [("/".join(k.key for k in p), i) for p, i in ids] == [
+        (p, i) for i, p in enumerate(DENSE_LEAVES[arch])]

@@ -242,6 +242,32 @@ def test_train_mgd_accepts_prebuilt_driver():
         train_mgd(_loss, _params(), drv, lambda i: BATCH, 10, log=None)
 
 
+@pytest.mark.parametrize("chunk", [10, 1])
+def test_train_mgd_donates_its_own_weights_not_the_callers(chunk):
+    """A program of several steps donates the weights it is handed, so it
+    holds one copy of them; a program of one step donates nothing (its
+    update writes fresh buffers).  The caller's arrays stay readable and
+    unchanged either way."""
+    from repro.training.train_loop import train_mgd
+    p0 = _params(4)
+    before = jax.tree_util.tree_map(np.asarray, p0)
+    handed = []
+
+    def keep(p):
+        handed.append(p)
+        return {}
+
+    drv = driver("discrete", DriverConfig(dtheta=1e-2, eta=1.0), _loss)
+    res = train_mgd(None, p0, drv, lambda i: BATCH, 3 * chunk,
+                    loop=repro.TrainLoopConfig(chunk=chunk, eval_fn=keep,
+                                               eval_every=chunk, log=None))
+    _assert_trees_equal(p0, before)
+    assert len(handed) == 3
+    assert all(leaf.is_deleted() == (chunk > 1) for p in handed[:-1]
+               for leaf in jax.tree_util.tree_leaves(p))
+    _assert_trees_equal(res.params, handed[-1])
+
+
 def test_train_mgd_discrete_unchanged_by_redesign(tmp_path):
     """The historical call shape (loss_fn + MGDConfig) still trains and
     still resumes from its own checkpoints."""

@@ -75,3 +75,63 @@ def test_spans_change_no_result(traced):
     plain = _train()
     assert plain.history == res.history
     assert jnp.array_equal(plain.params["w"], res.params["w"])
+
+
+def _fused_train(arch, out):
+    """A traced fused central-pair run of a smoke transformer: (history,
+    [(name, stats)] of the repro.* host spans)."""
+    from jax.profiler import ProfileData
+
+    from repro.configs import get_smoke_config
+    from repro.models import make_transformer_probe_fn, model_init, model_loss
+    cfg = get_smoke_config(arch).replace(dtype="float32")
+    params = model_init(cfg, jax.random.PRNGKey(0))
+
+    def sample(i):
+        toks = jax.random.randint(jax.random.fold_in(jax.random.PRNGKey(1), i),
+                                  (2, 32), 0, cfg.vocab)
+        return {"tokens": toks, "labels": toks}
+
+    with jax.profiler.trace(str(out)):
+        res = repro.train(
+            None, params, repro.driver(
+                "discrete", DriverConfig(fused=True, mode="central",
+                                         kernel_impl="interpret",
+                                         dtheta=2 ** -6, eta=2e-3),
+                lambda p, b: model_loss(p, cfg, b),
+                probe_fn=make_transformer_probe_fn(cfg)),
+            sample, 4, loop=repro.TrainLoopConfig(chunk=CHUNK, log=None))
+    path = sorted(out.rglob("*.xplane.pb"))[-1]
+    spans = sorted(((e.start_ns, e.name, dict(e.stats))
+                    for plane in ProfileData.from_file(str(path)).planes
+                    for line in plane.lines for e in line.events
+                    if e.name.startswith("repro.")))
+    return res.history, [(n, st) for _, n, st in spans]
+
+
+def test_dense_config_reads_no_expert_counter(tmp_path):
+    """A dense config's loop reads its four metrics and writes no
+    ``repro.moe.rows`` span: no extra metric, no extra read."""
+    history, spans = _fused_train("qwen3-14b", tmp_path / "dense")
+    assert {n for n, _ in spans} == set(SPANS)
+    for _, rec in history:
+        assert set(rec) == {"cost", "c_tilde", "updated", "grad_norm_proxy"}
+
+
+def test_expert_counter_rides_the_loops_read(tmp_path):
+    """DeepSeek-V3's dropless layer hands back the rows each probe stream
+    routed to each held expert: the loop takes them in its one read per
+    program and writes them as the span ``repro.moe.rows``'s stats."""
+    import numpy as np
+    history, spans = _fused_train("deepseek-v3-671b", tmp_path / "moe")
+    rows = [st for n, st in spans if n == "repro.moe.rows"]
+    assert len(rows) == len(history) == 4 // CHUNK
+    for st, (_, rec) in zip(rows, history):
+        shape = tuple(int(x) for x in st["shape"].split("x"))
+        values = np.array([int(x) for x in st["values"].split()])
+        assert shape == (1, 2, 8)
+        np.testing.assert_array_equal(values.reshape(shape),
+                                      np.array(rec["moe_rows"]))
+    order = [n for n, _ in spans]
+    assert order == ["repro.loop.dispatch", "repro.loop.read",
+                     "repro.moe.rows"] * (4 // CHUNK)
