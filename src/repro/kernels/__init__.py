@@ -14,13 +14,18 @@
 * ``mgd_update_window``     — the same update in the optimizer's exact
   sequential-axpy float order (bit-identical f32 trajectories; this is the
   variant ``MGDConfig(fused=True)`` consumes).
+* ``mla_flash_attention``   — causal multi-head latent attention with the
+  score blocks kept in VMEM (qk and v widths apart, one rope key shared
+  by every head); expand-form MLA's attention.
 
 ``ops`` holds the jit'd dispatch wrappers (pallas / interpret / ref);
 ``ref`` holds the pure-jnp oracles that share the exact counter hash.
 """
 from . import ops, ref
-from .ops import (mgd_update, mgd_update_window, perturbed_grouped_matmul,
-                  perturbed_matmul, perturbed_matmul_pair)
+from .ops import (mgd_update, mgd_update_window, mla_flash_attention,
+                  perturbed_grouped_matmul, perturbed_matmul,
+                  perturbed_matmul_pair)
 
 __all__ = ["ops", "ref", "perturbed_matmul", "perturbed_matmul_pair",
-           "perturbed_grouped_matmul", "mgd_update", "mgd_update_window"]
+           "perturbed_grouped_matmul", "mgd_update", "mgd_update_window",
+           "mla_flash_attention"]

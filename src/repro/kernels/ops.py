@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 
 from . import ref as _ref
+from .flash_attention import (
+    mla_flash_attention as _mla_flash_attention_pallas)
 from .grouped_matmul import grouped_matmul_ref as _grouped_matmul_ref
 from .grouped_matmul import (
     perturbed_grouped_matmul as _perturbed_grouped_matmul_pallas)
@@ -141,6 +143,19 @@ def perturbed_grouped_matmul(xs, w, lseed, start, nblk, last, *, signs,
         interpret=(impl == "interpret"),
         name="perturbed_grouped_matmul_pair" if pair
         else "perturbed_grouped_matmul")
+
+
+def mla_flash_attention(q, kv, k_rope, *, scale, impl=None, blocks=None):
+    """Causal MLA attention, q [B, H, S, dn + dr] heads-major with the rope
+    applied, kv [B, S, H·(dn + dv)] as ``wkv_b`` writes it, the shared
+    rope key k_rope [B, S, dr] → [B, S, H·dv]
+    (``flash_attention.mla_flash_attention``)."""
+    impl = impl or default_impl()
+    if impl == "ref":
+        return _ref.mla_attention_ref(q, kv, k_rope, scale=scale)
+    return _mla_flash_attention_pallas(q, kv, k_rope, scale=scale,
+                                       blocks=blocks,
+                                       interpret=(impl == "interpret"))
 
 
 def _as_matrix(w):

@@ -40,6 +40,24 @@ def perturbed_matmul_pair_ref(xp, xm, w, lseed, *, dtheta, out_dtype=None):
     return yp, ym
 
 
+def mla_attention_ref(q, kv, k_rope, *, scale):
+    """Causal MLA attention in f32 with every score materialized:
+    ``ops.mla_flash_attention``'s operands and result."""
+    f32 = jnp.float32
+    b, h, s, dq = q.shape
+    dr = k_rope.shape[-1]
+    kv = kv.reshape(b, s, h, -1).astype(f32)
+    k = jnp.concatenate([kv[..., :dq - dr], jnp.broadcast_to(
+        k_rope[:, :, None].astype(f32), (b, s, h, dr))], axis=-1)
+    hi = jax.lax.Precision.HIGHEST
+    scores = jnp.einsum("bhqd,bkhd->bhqk", q.astype(f32), k,
+                        precision=hi) * scale
+    causal = jnp.tril(jnp.ones((s, s), bool))
+    p = jax.nn.softmax(jnp.where(causal, scores, -jnp.inf), axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", p, kv[..., dq - dr:], precision=hi)
+    return out.reshape(b, s, -1).astype(q.dtype)
+
+
 def mgd_update_ref(w, lseeds, coefs, *, eta, dtheta):
     """W − (η/Δθ)·Σ_j coefs[j]·signs_j — materializes every window sign."""
     acc = jnp.zeros(w.shape, jnp.float32)

@@ -5,7 +5,8 @@ Queries and KV are low-rank compressed; only the compressed latent c_kv
 execution forms:
 
 * expand form (train / prefill): decompress K/V per position and run
-  standard causal attention — matmul-friendly at full sequence length.
+  causal attention through the Pallas kernel
+  ``kernels.flash_attention`` — matmul-friendly at full sequence length.
 * absorbed form (decode): fold W_UK into the query and W_UV into the
   output so attention runs directly against the compressed cache —
   per-step FLOPs O(H·(r + d_rope)) per cached token instead of
@@ -16,10 +17,13 @@ Equivalence of the two forms is asserted in tests/test_models.py.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
 from repro.core.utils import leaf_id_tree
+from repro.kernels import ops as kops
 from .attention import chunked_causal_attention, NEG_INF
 from .layers import dense_init, pdense, prmsnorm, rmsnorm_init
 from .rope import apply_rope, cfg_rope_freqs, cfg_rope_mscale, \
@@ -89,42 +93,64 @@ def _kv_latent(p, xs, cfg, positions, ids, probe, layer):
     return cs, krs
 
 
-def mla_streams(p, xs, positions, cfg, ids, probe, layer=None, *,
-                q_block=512, kv_block=512, impl="masked"):
+def _attend_xla(q, kv, k_rope, scale):
+    """``_attend`` through ``chunked_causal_attention`` (XLA): the path
+    its backward differentiates."""
+    b, h, s, dq = q.shape
+    dr = k_rope.shape[-1]
+    kv = kv.reshape(b, s, h, -1)
+    k = jnp.concatenate([kv[..., :dq - dr], jnp.broadcast_to(
+        k_rope[:, :, None], (b, s, h, dr))], axis=-1)
+    out = chunked_causal_attention(jnp.swapaxes(q, 1, 2), k,
+                                   kv[..., dq - dr:], scale=scale)
+    return out.reshape(b, s, -1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _attend(q, kv, k_rope, scale):
+    """Causal attention of heads-major q [B,H,S,dn+dr] against ``wkv_b``'s
+    output kv [B,S,H·(dn+dv)] and the shared rope key [B,S,dr], through
+    the Pallas kernel (``kernels.ops.mla_flash_attention``) →
+    [B,S,H·dv].  Differentiated through ``_attend_xla``."""
+    return kops.mla_flash_attention(q, kv, k_rope, scale=scale)
+
+
+def _attend_fwd(q, kv, k_rope, scale):
+    return _attend(q, kv, k_rope, scale), (q, kv, k_rope)
+
+
+def _attend_bwd(scale, res, g):
+    return jax.vjp(functools.partial(_attend_xla, scale=scale), *res)[1](g)
+
+
+_attend.defvjp(_attend_fwd, _attend_bwd)
+
+
+def mla_streams(p, xs, positions, cfg, ids, probe, layer=None):
     """Expand-form causal MLA over full sequences, one result per stream
     (``layers.pdense``'s stream convention; ``probe=None`` is the plain
     forward).  Returns (ys [B,S,d_model] per stream, caches (c_kv, k_rope)
     per stream)."""
-    b, s, _ = xs[0].shape
-    h = cfg.n_heads
-    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
     qs = _queries(p, xs, cfg, ids, probe, layer)
     cs, krs = _kv_latent(p, xs, cfg, positions, ids, probe, layer)
     kvs = pdense(p["wkv_b"], cs, ids["wkv_b"], probe, layer=layer)
     outs = []
     for (q_nope, q_rope), kv, k_rope in zip(qs, kvs, krs):
-        k_nope, v = jnp.split(kv.reshape(b, s, h, dn + dv), [dn], axis=-1)
         q = jnp.concatenate([q_nope, mla_rope(q_rope, positions, cfg)], -1)
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope, (b, s, h, dr))], axis=-1)
-        att = chunked_causal_attention(
-            q, k, v, q_block=q_block, kv_block=kv_block, impl=impl,
-            scale=softmax_scale(cfg))
-        outs.append(att.reshape(b, s, h * dv))
+        outs.append(_attend(jnp.swapaxes(q, 1, 2), kv, k_rope[:, :, 0],
+                            softmax_scale(cfg)))
     ys = pdense(p["wo"], tuple(outs), ids["wo"], probe, layer=layer)
     return ys, tuple((c, kr[:, :, 0, :]) for c, kr in zip(cs, krs))
 
 
-def mla_attention(p, x, positions, cfg, *, q_block=512, kv_block=512,
-                  impl="masked"):
+def mla_attention(p, x, positions, cfg):
     """Expand-form causal MLA over a full sequence: the one-stream, plain
     case of ``mla_streams``.
 
     Returns (y [B,S,d_model], cache = (c_kv, k_rope squeezed)).
     """
     (y,), (cache,) = mla_streams(p, (x,), positions, cfg, leaf_id_tree(p),
-                                 None, q_block=q_block, kv_block=kv_block,
-                                 impl=impl)
+                                 None)
     return y, cache
 
 

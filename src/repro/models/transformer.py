@@ -146,10 +146,7 @@ def block_apply(p, x, positions, cfg: ArchConfig):
     seq_ax = "sp" if cfg.seq_parallel else None
     xn = rmsnorm(p["ln1"], x, cfg.norm_eps)
     if cfg.use_mla:
-        att, cache = mla_attention(
-            p["attn"], xn, positions, cfg,
-            q_block=cfg.attn_q_block, kv_block=cfg.attn_kv_block,
-            impl=cfg.attn_impl)
+        att, cache = mla_attention(p["attn"], xn, positions, cfg)
     else:
         att, cache = attn_apply(p["attn"], xn, positions, cfg)
     x = x + att
@@ -433,9 +430,7 @@ def _pattn_apply(p, xs, positions, cfg: ArchConfig, ids, probe, layer):
 
 @jax.named_scope("repro.model.attn")
 def _pmla_apply(p, xs, positions, cfg: ArchConfig, ids, probe, layer):
-    ys, _ = mla_streams(p, xs, positions, cfg, ids, probe, layer,
-                        q_block=cfg.attn_q_block, kv_block=cfg.attn_kv_block,
-                        impl=cfg.attn_impl)
+    ys, _ = mla_streams(p, xs, positions, cfg, ids, probe, layer)
     return ys
 
 

@@ -216,6 +216,20 @@ def test_grouped_kernel_compiles_at_deepseek_v3_widths(one_chip, k, n):
     assert "perturbed_grouped_matmul_pair" in text
 
 
+@pytest.mark.parametrize("s", [8192, 8000], ids=["8192", "8000_padded"])
+def test_mla_flash_attention_compiles_at_deepseek_v3_widths(one_chip, s):
+    """The MLA kernel at one probe stream of the DeepSeek-V3 cell: 128
+    heads, q/k 128 + 64 against v 128, the sequence at its picked blocks
+    (and a length that is no multiple of them)."""
+    def sd(dims):
+        return jax.ShapeDtypeStruct(dims, BF16, sharding=one_chip)
+
+    text = _compile(lambda q, kv, kr: ops.mla_flash_attention(
+        q, kv, kr, scale=0.1353, impl="pallas"),
+        sd((1, 128, s, 192)), sd((1, s, 128 * 256)), sd((1, s, 64))).as_text()
+    assert "mla_flash_attention" in text
+
+
 def _pallas_calls(jaxpr, out):
     """(name, grid, block shapes) of every pallas_call in a jaxpr."""
     for e in jaxpr.eqns:

@@ -2,6 +2,7 @@
 bank of held experts, the published routing, the dropless chip-share
 expert layer, YaRN, and the fused ≡ materializing contract for the whole
 step (f32, interpret-mode kernels)."""
+import functools
 import math
 
 import jax
@@ -254,3 +255,116 @@ def test_yarn_matches_the_published_formula():
     scale = yarn_softmax_scale(get_config("deepseek-v3-671b"), 192)
     assert scale == pytest.approx(192 ** -0.5 * (0.1 * math.log(40) + 1) ** 2)
     assert scale == pytest.approx(0.1353, abs=1e-4)
+
+
+# --- MLA's causal attention through the Pallas kernel ----------------------
+
+def _mla_operands(dtype, b=2, h=3, s=40, dn=32, dr=16, dv=32, key=11):
+    """q [B,H,S,dn+dr] heads-major, kv [B,S,H·(dn+dv)], the shared rope
+    key [B,S,dr]: q/k width 48 against v width 32."""
+    kq, kkv, kr = jax.random.split(jax.random.PRNGKey(key), 3)
+    return (jax.random.normal(kq, (b, h, s, dn + dr)).astype(dtype),
+            jax.random.normal(kkv, (b, s, h * (dn + dv))).astype(dtype),
+            jax.random.normal(kr, (b, s, dr)).astype(dtype))
+
+
+# S = 40 is no multiple of any block here: the sequence is end-padded;
+# with 16-row blocks the diagonal cuts blocks and whole blocks are skipped
+@pytest.mark.parametrize("blocks", [(16, 16), (32, 16), (16, 32), None],
+                         ids=["16x16", "32x16", "16x32", "picked"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_mla_flash_attention_matches_oracles(dtype, blocks):
+    """The kernel (interpret mode) against the plain f32 oracle and the
+    XLA blockwise path (``chunked_causal_attention`` over the rope key
+    broadcast to every head).  f32 inputs: 2e-5.  bf16 inputs: the kernel
+    rounds p to bf16 for p·v and its output to bf16 (2^-8 each, relative
+    to outputs of unit scale), so 3e-2."""
+    from repro.models import mla
+
+    q, kv, kr = _mla_operands(jnp.dtype(dtype))
+    tol = 2e-5 if dtype == "float32" else 3e-2
+    got = ops.mla_flash_attention(q, kv, kr, scale=0.2, impl="interpret",
+                                  blocks=blocks)
+    assert got.shape == (2, 40, 3 * 32) and got.dtype == q.dtype
+    oracle = ops.mla_flash_attention(q, kv, kr, scale=0.2, impl="ref")
+    xla = mla._attend_xla(*(x.astype(jnp.float32) for x in (q, kv, kr)),
+                          scale=0.2)
+    for want in (oracle, xla):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(want, np.float32),
+                                   atol=tol, rtol=tol)
+
+
+def test_mla_flash_attention_is_causal_and_shares_the_rope_key():
+    """A later key or value moves no earlier output; the rope key of one
+    position moves every head's output from that position on."""
+    q, kv, kr = _mla_operands(jnp.float32, b=1, s=48)
+    run = functools.partial(ops.mla_flash_attention, scale=0.2,
+                            impl="interpret", blocks=(16, 16))
+    base = run(q, kv, kr)
+    moved = run(q, kv.at[:, 30].add(1.0), kr)
+    np.testing.assert_array_equal(np.asarray(moved[:, :30]),
+                                  np.asarray(base[:, :30]))
+    assert not np.allclose(moved[:, 30:], base[:, 30:])
+    moved = np.asarray(run(q, kv, kr.at[:, 20].add(1.0)) - base)
+    np.testing.assert_array_equal(moved[:, :20], 0.0)
+    for h in range(3):
+        assert np.abs(moved[:, 20:, h * 32:(h + 1) * 32]).max() > 1e-4
+
+
+def test_mla_streams_match_the_xla_path_for_two_streams(monkeypatch):
+    """``mla_streams`` under a central probe pair (two streams that the
+    perturbed projections set apart) through the kernel against the same
+    layer with its attention on the XLA path: each stream, with the rope,
+    the heads-major q and the shared rope key."""
+    from repro.models import mla
+
+    cfg = SMOKE
+    p = mla.mla_init(jax.random.PRNGKey(3), cfg, jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(10), (2, 40, cfg.d_model))
+    pos = jnp.broadcast_to(jnp.arange(40, dtype=jnp.int32), (2, 40))
+    probe = pert.Probe(jnp.int32(3), jnp.uint32(5), pert.ProbeCtx(
+        signs=(1.0, -1.0), dtheta=0.1, impl="interpret"))
+    args = (p, (x, x), pos, cfg, leaf_id_tree(p), probe)
+    got, _ = mla.mla_streams(*args)
+    monkeypatch.setattr(mla, "_attend", mla._attend_xla)
+    want, _ = mla.mla_streams(*args)
+    assert len(got) == 2 and not np.allclose(got[0], got[1])
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-5,
+                                   rtol=2e-5)
+
+
+def test_mla_attention_differentiates_through_the_xla_path():
+    """Backprop through MLA (the baseline ``train_backprop``): the
+    kernel's custom VJP is the XLA path's, bit for bit."""
+    from repro.models import mla
+
+    q, kv, kr = _mla_operands(jnp.float32, b=1, s=32)
+    w = jax.random.normal(jax.random.PRNGKey(4), (1, 32, 96))
+
+    def loss(attend):
+        return lambda *a: jnp.sum(w * attend(*a, 0.2))
+
+    got = jax.grad(loss(mla._attend), argnums=(0, 1, 2))(q, kv, kr)
+    want = jax.grad(loss(mla._attend_xla), argnums=(0, 1, 2))(q, kv, kr)
+    for g, x in zip(got, want):
+        assert bool(jnp.any(g != 0))
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(x))
+
+
+def _jaxpr_text(cfg):
+    shapes = jax.eval_shape(lambda: model_init(cfg, jax.random.PRNGKey(0)))
+    toks = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    return str(jax.make_jaxpr(lambda p, b: model_loss(p, cfg, b))(
+        shapes, {"tokens": toks, "labels": toks}))
+
+
+@pytest.mark.parametrize("arch,calls", [
+    ("deepseek-v3-671b", True), ("qwen3-14b", False),
+    ("mistral-nemo-12b", False)])
+def test_only_mla_runs_the_flash_kernel(arch, calls):
+    """The DeepSeek-V3 smoke model's forward holds the
+    ``mla_flash_attention`` call; the dense GQA configs' do not."""
+    text = _jaxpr_text(get_smoke_config(arch))
+    assert ("name=mla_flash_attention" in text) == calls
